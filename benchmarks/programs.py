@@ -13,9 +13,9 @@ import repro.core as core
 from repro.core.func import flush, print as lprint
 
 
-def build_sources(scale: int, tmpdir: str | None = None, seed: int = 0):
-    """Synthetic datasets sized ``scale`` rows (taxi) and scale//4 (movies),
-    written as partitioned npz when tmpdir is given (out-of-core path)."""
+def build_arrays(scale: int, seed: int = 0) -> dict[str, dict[str, np.ndarray]]:
+    """The synthetic tables as host column arrays: ``scale`` taxi rows,
+    scale//4 ratings, scale//2 startups and 2000 movies, from ``seed``."""
     rng = np.random.default_rng(seed)
     n = scale
     taxi = {
@@ -53,6 +53,18 @@ def build_sources(scale: int, tmpdir: str | None = None, seed: int = 0):
         "unused1": rng.uniform(0, 1, max(n // 2, 100)),
         "unused2": rng.uniform(0, 1, max(n // 2, 100)),
     }
+    return {"taxi": taxi, "ratings": ratings, "movies": movies,
+            "startups": startups}
+
+
+def build_sources(scale: int, tmpdir: str | None = None, seed: int = 0,
+                  arrays: dict | None = None):
+    """Synthetic datasets sized ``scale`` rows (taxi) and scale//4 (movies),
+    written as partitioned npz when tmpdir is given (out-of-core path).
+    ``arrays`` (from ``build_arrays(scale, seed)``) skips regenerating."""
+    arrays = arrays or build_arrays(scale, seed)
+    taxi, ratings = arrays["taxi"], arrays["ratings"]
+    movies, startups = arrays["movies"], arrays["startups"]
     part = max(scale // 16, 1024)
     if tmpdir is not None:
         from repro.core.source import write_npz_source

@@ -949,6 +949,8 @@ def main(argv: list[str] | None = None) -> None:
         PYTHONPATH=src python -m benchmarks.run api_coverage
     """
     import sys
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     by_name = {fn.__name__: fn for fn in ALL_FIGURES}
     unknown = [a for a in argv if a not in by_name]

@@ -61,11 +61,8 @@ from .distributed import DistributedBackend  # noqa: E402
 
 
 def _device_count() -> int:
-    try:
-        import jax
-        return max(1, len(jax.devices()))
-    except Exception:  # noqa: BLE001 — planning must never crash
-        return 1
+    import jax
+    return len(jax.devices())
 
 
 def _broadcast_build_bytes() -> float:

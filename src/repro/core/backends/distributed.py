@@ -8,7 +8,7 @@ single jit+shard_map program per pipeline stage; reductions and group-bys
 compute shard-local partials and combine with ``jax.lax.psum`` over the data
 axis.  Group-by keys must be dictionary-coded / small-domain ints (the
 metadata store guarantees this for category columns), giving a dense
-``segment_sum`` of size G per shard — the same layout the MXU group-by
+``segment_sum`` of size G per shard — the same dense code domain the group-by
 kernel uses on TPU.
 
 Join, sort, and distinct are *native* (``physical.sharded``): broadcast-hash
@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ...compat import shard_map
 from .. import graph as G
 from .. import physical as X
 from ..context import LaFPContext
@@ -298,7 +297,7 @@ class DistributedBackend:
                                           else jnp.iinfo(col.dtype).min))
                 return r
 
-            f = shard_map(
+            f = jax.shard_map(
                 lambda c, v: _psum_combine(fn, local(c[0], v[0]), axis),
                 mesh=mesh,
                 in_specs=(P(axis), P(axis)),
@@ -337,24 +336,32 @@ class DistributedBackend:
         @partial(jax.jit, static_argnames=("gdom",))
         def run(karr, valid, vals, gdom):
             def local(k, v, vals):
+                # sums and counts go through the kernel layer (the Pallas
+                # group-by on TPU): int sums as exact (hi, lo) words, and
+                # f32 sums accumulate per lane and block rather than in
+                # XLA's scatter-add, which drifts by ~3e-4 at 1e6+ rows a
+                # group
+                from ...kernels import ops as K
                 k = jnp.where(v, k, gdom)  # invalid rows to overflow bucket
                 outs = {}
-                cnt = jax.ops.segment_sum(v.astype(jnp.float32), k, gdom + 1)
+                cnt = K.groupby_sum(k, v.astype(jnp.int32), gdom + 1)
                 for out_name, (c, fn) in n.aggs.items():
                     if fn == "count":
                         outs[out_name] = cnt
                     elif fn in ("sum", "mean"):
-                        s = jax.ops.segment_sum(
-                            jnp.where(v, vals[c].astype(jnp.float32), 0.0), k,
-                            gdom + 1)
-                        outs[out_name] = jnp.stack([s, cnt]) if fn == "mean" else s
-                    elif fn == "min":
-                        big = jnp.asarray(jnp.inf, jnp.float32)
-                        x = jnp.where(v, vals[c].astype(jnp.float32), big)
-                        outs[out_name] = jax.ops.segment_min(x, k, gdom + 1)
-                    elif fn == "max":
-                        x = jnp.where(v, vals[c].astype(jnp.float32), -jnp.inf)
-                        outs[out_name] = jax.ops.segment_max(x, k, gdom + 1)
+                        x = jnp.where(v, vals[c], 0)
+                        outs[out_name] = (
+                            K.groupby_sum(k, x, gdom + 1) if x.dtype.kind == "f"
+                            else jnp.stack(K.groupby_sum_words(k, x, gdom + 1)))
+                    else:
+                        x = vals[c]
+                        lo, hi = ((-jnp.inf, jnp.inf) if x.dtype.kind == "f"
+                                  else (jnp.iinfo(x.dtype).min,
+                                        jnp.iinfo(x.dtype).max))
+                        seg = (jax.ops.segment_min if fn == "min"
+                               else jax.ops.segment_max)
+                        x = jnp.where(v, x, hi if fn == "min" else lo)
+                        outs[out_name] = seg(x, k, gdom + 1)
                 outs["__count"] = cnt
                 return outs
 
@@ -370,26 +377,31 @@ class DistributedBackend:
                         arr, axis)
                 return comb
 
-            return shard_map(
+            # check_vma off: the Pallas kernel's outputs carry no
+            # varying-axes annotation for shard_map to check
+            return jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(P(axis), P(axis)) + tuple(P(axis) for _ in value_cols),
-                out_specs=P())(karr, valid,
+                out_specs=P(), check_vma=False)(karr, valid,
                                *[vals[c] for c in sorted(value_cols)])
 
+        from ...kernels import ops as K
         vals = {c: value_cols[c] for c in sorted(value_cols)}
         outs = run(karr, t.valid, vals, G_dom)
-        present = np.asarray(outs["__count"][:G_dom]) > 0
-        groups = np.nonzero(present)[0]
-        result = {key: groups.astype(np.asarray(karr).dtype)}
+        cnt = np.asarray(outs["__count"][:G_dom])
+        groups = np.nonzero(cnt > 0)[0]
+        result = {key: groups.astype(np.dtype(karr.dtype))}
         for out_name, (_c, fn) in n.aggs.items():
             arr = outs[out_name]
+            # int sums come back as (hi, lo) words, exact in int64 here
+            arr = (K.words_to_int64(arr[0], arr[1]) if arr.ndim == 2
+                   else np.asarray(arr))[:G_dom]
             if fn == "mean":
-                s, c = np.asarray(arr[0][:G_dom]), np.asarray(arr[1][:G_dom])
-                result[out_name] = (s / np.maximum(c, 1))[groups]
+                result[out_name] = (arr / np.maximum(cnt, 1))[groups]
             elif fn == "count":
-                result[out_name] = np.asarray(arr[:G_dom]).astype(np.int64)[groups]
+                result[out_name] = arr.astype(np.int64)[groups]
             else:
-                result[out_name] = np.asarray(arr[:G_dom])[groups]
+                result[out_name] = arr[groups]
         return result
 
 

@@ -6,7 +6,7 @@ inside Assign expressions) into one :class:`graph.FusedRowwise` node, the
 same move as Dask's low-level ``fuse`` pass.  The physical layer then
 executes the whole chain as a single composed pass: one jitted device
 dispatch on the jnp path (``physical.rowwise.apply_fused_rowwise``, which
-compacts Filter survivors with the ``repro.kernels`` filter_compact kernel)
+compacts Filter survivors once at the end)
 and one chunk-loop body on the streaming path — no intermediate tables
 between members.
 

@@ -393,6 +393,8 @@ def zone_map_pruning(roots: list[G.Node], trace=None
         if not ok or not isinstance(node, G.Scan):
             continue
         scan = node
+        if len(parents.get(scan.id, [])) != 1:
+            continue  # another consumer of the scan needs every partition
         usable = [c for c in _conjuncts(n.predicate)
                   if isinstance(c, E.BinOp) and not (c.used_cols() & modified)]
         if not usable:

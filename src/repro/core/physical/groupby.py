@@ -1,8 +1,8 @@
 """Group-by aggregation: factorize keys → dense segment reductions.
 
 The jnp path routes its sum-shaped reductions (sum/mean/count) through
-``repro.kernels.ops.groupby_sum`` — the MXU one-hot kernel when the kernel
-config resolves to "pallas", its jnp oracle otherwise; the partial/combine
+``repro.kernels.ops.groupby_sum`` — the Pallas masked-sum kernel when the
+kernel config resolves to "pallas", its jnp oracle otherwise; the partial/combine
 pair is what the
 streaming backend uses for out-of-core aggregation (memory scales with the
 number of groups, not rows)."""
@@ -71,6 +71,10 @@ def apply_groupby_agg(table: Table, keys: Sequence[str],
         out = decode(groups)
         for out_name, (col, fn) in aggs.items():
             out[out_name] = _segment_agg_jax(table, col, fn, inv, num)
+        if not all(is_jax(v) for v in out.values()):
+            # an int sum left int32: the (num-row) result moves to the
+            # host, where its int64 column can live
+            out = {k: np.asarray(v) for k, v in out.items()}
     else:
         groups, inv = np.unique(combined, return_inverse=True)
         num = int(groups.shape[0])
@@ -81,17 +85,23 @@ def apply_groupby_agg(table: Table, keys: Sequence[str],
 
 
 def _segment_agg_jax(table, col, fn, seg_ids, num):
-    # sum-shaped aggregations dispatch through the kernel layer: the MXU
-    # one-hot kernel on TPU ("pallas"), the segment_sum oracle elsewhere
+    # sum-shaped aggregations dispatch through the kernel layer: the Pallas
+    # kernel on TPU ("pallas"), the segment_sum oracle elsewhere
     from ...kernels import ops as K
-    ones = jnp.ones((seg_ids.shape[0],), jnp.float32)
+    ones = jnp.ones((seg_ids.shape[0],), jnp.int32)   # exact counts
     if fn == "count":
         return K.groupby_sum(seg_ids, ones, num).astype(jnp.int64)
     vals = table[col]
-    if vals.dtype.kind in "iub" and vals.dtype.itemsize < 4:
-        vals = vals.astype(jnp.int32)   # widen narrow ints: no int8 accumulate
-    if fn == "sum":
+    if fn == "sum" and vals.dtype.kind == "f":
         return K.groupby_sum(seg_ids, vals, num)
+    if fn == "sum":
+        # exact int sums: device int32 while every total fits, else host
+        # int64 (x64 is off, so the device has no wider int)
+        total = K.words_to_int64(*K.groupby_sum_words(seg_ids, vals, num))
+        info = np.iinfo(np.int32)
+        if total.size and (total.min() < info.min or total.max() > info.max):
+            return total
+        return jnp.asarray(total.astype(np.int32))
     if fn == "mean":
         s = K.groupby_sum(seg_ids, vals.astype(jnp.float32), num)
         c = K.groupby_sum(seg_ids, ones, num)

@@ -109,7 +109,7 @@ def _fused_jax_fn(ops: tuple, cfg):
     even where a dynamic gather is cheaper."""
     import jax
 
-    key = (tuple(m.key()[:-1] for m in ops), cfg.resolved(), cfg.interpret)
+    key = (tuple(m.key()[:-1] for m in ops), cfg.resolved())
     fn = _FUSED_JIT_CACHE.get(key)
     if fn is not None:
         return fn
@@ -174,11 +174,10 @@ def apply_fused_rowwise(table: Table, ops, impl: str | None = None) -> Table:
     """Execute a FusedRowwise chain as one composed pass.
 
     jnp tables: one device dispatch through a cached jitted body (no
-    intermediate tables); Filter-terminated chains compact survivors with
-    the ``repro.kernels`` filter_compact kernel when ``impl`` resolves to
-    "pallas" (TPU), and via XLA's dynamic boolean gather on "xla" hosts
-    where the kernel's scatter packing loses to a plain gather.  numpy
-    tables (streaming chunks) and any chain that fails to trace fall back
+    intermediate tables); Filter-terminated chains compact survivors on
+    the device through XLA's scatter when ``impl`` resolves to "pallas"
+    (TPU), and via a host boolean gather on "xla" hosts.  numpy tables
+    (streaming chunks) and chains whose members call host code fall back
     to op-at-a-time members — identical semantics, just without the
     single-dispatch win."""
     if xp_of(table) is np:
@@ -186,10 +185,14 @@ def apply_fused_rowwise(table: Table, ops, impl: str | None = None) -> Table:
         for m in ops:
             out = _apply_member(out, m)
         return out
+    import jax.errors as je
     cfg = _kernel_cfg(impl)
     try:
         cols, mask = _fused_jax_fn(tuple(ops), cfg)(dict(table))
-    except Exception:  # noqa: BLE001 — untraceable chain: run unfused
+    except (je.TracerArrayConversionError, je.ConcretizationTypeError,
+            je.TracerBoolConversionError, je.TracerIntegerConversionError):
+        # a member calls host code on traced values (an opaque UDF): run
+        # unfused; compile and runtime failures propagate
         out = table
         for m in ops:
             out = _apply_member(out, m)
@@ -198,12 +201,14 @@ def apply_fused_rowwise(table: Table, ops, impl: str | None = None) -> Table:
     if mask is None:
         return cols
     if cfg.resolved() == "pallas":
+        # on the TPU, XLA's scatter compacts a 2e7-row column in 0.15 s
+        # warm against 3.4 s for the Pallas kernel and its stitch (v5e,
+        # chip_smoke.py), so the device path compacts through XLA
         from ...kernels import ops as K
-        out, count = {}, None
-        for c, v in cols.items():
-            out[c], count = K.filter_compact(v, mask, cfg)
-        k = int(count) if count is not None else 0
-        return {c: v[:k] for c, v in out.items()}
+        packed, count = K.filter_compact_columns(
+            tuple(cols.values()), mask, K.KernelConfig(impl="xla"))
+        k = int(count)
+        return {c: v[:k] for c, v in zip(cols, packed)}
     # xla hosts: jax's eager dynamic gather re-dispatches per column and
     # loses badly to one host boolean gather; arrays round-trip through
     # numpy (near zero-copy on CPU) and come back device-resident

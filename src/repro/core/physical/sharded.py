@@ -266,8 +266,11 @@ def _broadcast_hash_join(probe: ShardedTable, pcode: jax.Array, build: dict,
     binary-search its sorted key codes on device, and emit the probe layout
     with gathered payload columns and an updated validity mask.  Never
     touches host memory for the probe side."""
+    # the build side is replicated on the probe's mesh (jnp.asarray would
+    # commit it to one device and pull every shard's probe through it)
+    replicated = NamedSharding(probe.valid.sharding.mesh, P())
     order = np.argsort(bcode, kind="stable")
-    bsorted = jnp.asarray(bcode[order].astype(np.int32))
+    bsorted = jax.device_put(bcode[order].astype(np.int32), replicated)
     B = int(bsorted.shape[0])
     idx = jnp.searchsorted(bsorted, pcode.astype(jnp.int32))
     idx_c = jnp.clip(idx, 0, B - 1)
@@ -284,14 +287,15 @@ def _broadcast_hash_join(probe: ShardedTable, pcode: jax.Array, build: dict,
         if k in on:
             continue
         name = k + suffixes[1] if k in overlap else k
-        col_sorted = jnp.asarray(v[order])
+        col_sorted = jax.device_put(v[order], replicated)
         taken = jnp.take(col_sorted, idx_c)
         if how == "left":
             if v.dtype.kind == "f":
                 taken = jnp.where(matched, taken, jnp.nan)
             else:
                 # mirror the host kernel: unmatched rows read build row 0
-                taken = jnp.where(matched, taken, jnp.asarray(v[0]))
+                taken = jnp.where(matched, taken,
+                                  jnp.asarray(v[0], taken.dtype))
         out[name] = taken
     valid = probe.valid & matched if how == "inner" else probe.valid
     return ShardedTable(out, valid)

@@ -101,23 +101,25 @@ def _zonemap(arrays: Mapping[str, np.ndarray]) -> dict:
     When the kernel config resolves to a device implementation ("pallas"
     on TPU hosts) numeric columns route through the blocked
     ``repro.kernels.ops.zonemap`` kernel; host builds keep the numpy fast
-    path — same contract, no device round-trip."""
-    kernel = None
-    try:
-        from ..kernels import ops as _K
-        if _K.get_kernel_config().resolved() == "pallas":
-            kernel = _K
-    except Exception:  # noqa: BLE001 — stats are best-effort
-        kernel = None
+    path — same contract, no device round-trip.  The bounds must hold for
+    the host values that pushed-down predicates see: 64-bit ints, which
+    the device would wrap to 32 bits, stay on the host, and float64 bounds
+    computed in f32 are widened outward by one f32 step."""
+    from ..kernels import ops as K
+    on_device = K.get_kernel_config().resolved() == "pallas"
     zm = {}
     for name, arr in arrays.items():
-        if arr.dtype.kind in "ifu" and arr.size:
-            if kernel is not None:
-                mins, maxs = kernel.zonemap(arr)
-                zm[name] = (np.asarray(mins).min().item(),
-                            np.asarray(maxs).max().item())
-            else:
-                zm[name] = (arr.min().item(), arr.max().item())
+        if arr.dtype.kind not in "ifu" or not arr.size:
+            continue
+        if on_device and (arr.dtype.kind == "f" or arr.dtype.itemsize <= 4):
+            mins, maxs = K.zonemap(arr)
+            lo, hi = np.asarray(mins).min(), np.asarray(maxs).max()
+            if arr.dtype.itemsize > 4:
+                lo = np.nextafter(lo, np.float32(-np.inf))
+                hi = np.nextafter(hi, np.float32(np.inf))
+            zm[name] = (lo.item(), hi.item())
+        else:
+            zm[name] = (arr.min().item(), arr.max().item())
     return zm
 
 

@@ -1,22 +1,25 @@
-"""MXU group-by aggregation kernel (TPU adaptation of LaFP's group-by hot
-path, DESIGN §2).
+"""Group-by sum kernel (TPU adaptation of LaFP's group-by hot path,
+DESIGN §2).
 
 GPU/CPU dataframe engines aggregate via hash tables — branchy scalar probing
-that has no TPU analogue.  The TPU-native rethink: per row-block, build a
-one-hot matrix of the group codes and *matmul* it against the value block on
-the MXU:
+that has no TPU analogue.  Here the dense code domain that dict-encoded
+(category) keys guarantee turns the aggregation into a masked reduction:
+codes and value columns are laid out lane-dense as ``(rows, 128)`` tiles,
+and each grid step adds, for every group ``g``,
 
-    out[g, v] += Σ_j onehot[j, g] · values[j, v]      (Gp,B)·(B,Vp)
+    acc[v, g, :] += Σ_rows where(codes == g, values[v], 0)
 
-The output block (Gp, Vp) stays resident in VMEM across all grid steps
-(constant index_map), so the aggregation is a single pass over HBM with
-arithmetic intensity B·G·V / (B·V) = G — compute-bound for G ≥ ~100, versus
-the memory-bound scatter a hash aggregation would be.
+into a ``(V, G, 128)`` per-lane accumulator that stays resident in VMEM
+across the grid (constant index_map).  One pass over HBM with no padding of
+the value columns; the final 128-lane reduction happens outside the kernel.
 
-Block shapes: rows B=256 (sublane multiple), groups padded to 8·k, value
-columns padded to 128·k (lane width).  Dict-encoded (category) key columns
-from the metadata store guarantee a dense, bounded code domain — the same
-invariant the distributed backend's segment-sum path uses.
+Floats sum on the VPU in f32.  Ints and bools sum exactly as two int32
+words, ``hi · 2^16 + lo``: each value adds its low 16 bits to ``lo`` and
+the rest (``v >> 16``) to ``hi``, and every grid step carries ``lo``'s
+overflow into ``hi``, so no word wraps while a group's total stays below
+2^47 (a one-hot MXU matmul would round ints above 2^24 through f32, and a
+plain int32 sum would wrap past 2^31).  Codes outside [0, num_groups)
+contribute nothing.
 """
 from __future__ import annotations
 
@@ -26,61 +29,107 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import LANES, SUBLANES, interpret_mode, round_up, row_tiles
 
-def _groupby_kernel(codes_ref, values_ref, out_ref, *, num_groups_padded: int):
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+WORD_BITS = 16                  # int sums: hi · 2^WORD_BITS + lo
+_LO_MASK = (1 << WORD_BITS) - 1
+
+
+def _groupby_kernel(codes_ref, values_ref, *out_refs, num_groups: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        for ref in out_refs:
+            ref[...] = jnp.zeros_like(ref)
 
-    codes = codes_ref[...]            # (B,)
-    values = values_ref[...]          # (B, Vp) f32
-    groups = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0],
-                                                  num_groups_padded), 1)
-    onehot = (codes[:, None] == groups).astype(jnp.float32)   # (B, Gp)
-    # MXU: (Gp, B) @ (B, Vp) — accumulate into the resident output block
-    contrib = jax.lax.dot_general(
-        onehot, values,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                    # (Gp, Vp)
-    out_ref[...] += contrib
+    codes = codes_ref[...]                          # (Rb, 128) int32
+    n_vals = values_ref.shape[0]
+
+    def lane_sum(hit, x):                           # (1, 128) lane partials
+        return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=0,
+                       keepdims=True)
+
+    def body(g, carry):
+        hit = codes == g
+        for v in range(n_vals):
+            vals = values_ref[v]                    # (Rb, 128)
+            if len(out_refs) == 1:                  # f32 sum
+                out_refs[0][v, pl.ds(g, 1), :] += lane_sum(hit, vals)
+                continue
+            # int words; a block's lo partial is < Rb · 2^16, so Rb < 2^15
+            hi_ref, lo_ref = out_refs
+            lo = lo_ref[v, pl.ds(g, 1), :] + lane_sum(hit, vals & _LO_MASK)
+            hi_ref[v, pl.ds(g, 1), :] += (lane_sum(hit, vals >> WORD_BITS)
+                                          + (lo >> WORD_BITS))
+            lo_ref[v, pl.ds(g, 1), :] = lo & _LO_MASK
+        return carry
+
+    jax.lax.fori_loop(0, num_groups, body, 0)
 
 
-def _pad_to(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-@functools.partial(jax.jit, static_argnames=("num_groups", "block_rows",
-                                             "interpret"))
 def groupby_sum(codes: jax.Array, values: jax.Array, num_groups: int,
-                block_rows: int = 256, interpret: bool = True) -> jax.Array:
-    """Segment-sum values (N,) or (N, V) by codes (N,) → (G,) or (G, V).
+                block_rows: int = 64 * 1024) -> jax.Array:
+    """Segment-sum values (N,) or (N, V) by codes (N,) → (G,) or (G, V):
+    f32 for float values; for ints and bools int32, which wraps past 2^31
+    as XLA's int32 segment sum does (``groupby_sum_words`` keeps the
+    exact total).  ``block_rows`` elements per grid step (a multiple of
+    1024)."""
+    out = _groupby_sum(codes, values, num_groups, block_rows,
+                       interpret_mode())
+    if isinstance(out, tuple):
+        hi, lo = out
+        return (hi << WORD_BITS) + lo
+    return out
 
-    Rows with codes outside [0, num_groups) contribute nothing (they hit
-    padded one-hot columns)."""
+
+def groupby_sum_words(codes: jax.Array, values: jax.Array, num_groups: int,
+                      block_rows: int = 64 * 1024
+                      ) -> tuple[jax.Array, jax.Array]:
+    """Exact segment sums of int or bool values as int32 words ``(hi, lo)``,
+    each (G,) or (G, V): the sum is ``hi · 2^16 + lo`` with ``0 <= lo <
+    2^16``, exact while it stays below 2^47."""
+    if jnp.dtype(values.dtype).kind == "f":
+        raise TypeError("groupby_sum_words sums int or bool values")
+    return _groupby_sum(codes, values, num_groups, block_rows,
+                        interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _groupby_sum(codes, values, num_groups, block_rows, interpret):
     squeeze = values.ndim == 1
     if squeeze:
         values = values[:, None]
     n, v = values.shape
-    gp = _pad_to(max(num_groups, 8), 8)
-    vp = _pad_to(max(v, 128), 128)
-    nb = _pad_to(max(n, block_rows), block_rows)
-    codes_p = jnp.full((nb,), gp, jnp.int32).at[:n].set(
-        codes.astype(jnp.int32))                    # pad rows → dead group
-    values_p = jnp.zeros((nb, vp), jnp.float32).at[:n, :v].set(
-        values.astype(jnp.float32))
-    grid = nb // block_rows
-    out = pl.pallas_call(
-        functools.partial(_groupby_kernel, num_groups_padded=gp),
-        grid=(grid,),
+    words = jnp.dtype(values.dtype).kind != "f"
+    acc = jnp.int32 if words else jnp.float32
+    rb, rows = row_tiles(n, block_rows)
+    pad = rows * LANES - n
+    codes_p = jnp.pad(codes.astype(jnp.int32), (0, pad),
+                      constant_values=-1).reshape(rows, LANES)  # pad → no group
+    values_p = jnp.pad(values.astype(acc).T, ((0, 0), (0, pad))
+                       ).reshape(v, rows, LANES)
+    gp = round_up(max(num_groups, 1), SUBLANES)
+    out_block = pl.BlockSpec((v, gp, LANES), lambda i: (0, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((v, gp, LANES), acc)
+    outs = pl.pallas_call(
+        functools.partial(_groupby_kernel, num_groups=num_groups),
+        grid=(rows // rb,),
         in_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows, vp), lambda i: (i, 0)),
+            pl.BlockSpec((rb, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((v, rb, LANES), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((gp, vp), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((gp, vp), jnp.float32),
+        out_specs=[out_block] * (2 if words else 1),
+        out_shape=[out_shape] * (2 if words else 1),
         interpret=interpret,
     )(codes_p, values_p)
-    out = out[:num_groups, :v]
-    return out[:, 0] if squeeze else out
+
+    def groups_by_values(x):                        # (V, G) → (G, V) or (G,)
+        x = x[:, :num_groups].T
+        return x[:, 0] if squeeze else x
+
+    if not words:
+        return groups_by_values(outs[0].sum(axis=2))
+    hi, lo = outs
+    lo = lo.sum(axis=2)                             # < 128 · 2^16
+    hi = hi.sum(axis=2) + (lo >> WORD_BITS)
+    return groups_by_values(hi), groups_by_values(lo & _LO_MASK)

@@ -1,8 +1,8 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
 ``impl`` selects:
-* ``"pallas"``   — TPU-target kernels (validated with interpret=True on CPU;
-                   on a real TPU pass interpret=False via KernelConfig)
+* ``"pallas"``   — TPU-target kernels: compiled by Mosaic on a TPU,
+                   interpreted on any other host (``platform.py``)
 * ``"xla"``      — the pure-jnp reference path (production fallback; also
                    the oracle used in tests)
 
@@ -15,23 +15,32 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import ref
-from .filter_compact import filter_compact as _filter_compact_pallas
+from .filter_compact import filter_compact_columns as _compact_pallas
+from .groupby_sum import WORD_BITS
 from .groupby_sum import groupby_sum as _groupby_sum_pallas
+from .groupby_sum import groupby_sum_words as _groupby_sum_words_pallas
+from .platform import LANES, on_tpu
 from .zonemap import zonemap as _zonemap_pallas
+
+# VMEM the group-by kernel's resident accumulators may take: (V, G, 128)
+# 32-bit words, twice that for exact int sums.  A capacity limit (larger
+# code domains aggregate through the XLA segment sum), not a speed
+# crossover: the kernel's cost grows as G · N and no run has timed either
+# side of it.
+GROUPBY_VMEM_BYTES = 4 << 20
 
 
 @dataclasses.dataclass(frozen=True)
 class KernelConfig:
     impl: str = "auto"          # auto | pallas | xla
-    interpret: bool = True      # Pallas interpret mode (CPU validation)
 
     def resolved(self) -> str:
         if self.impl != "auto":
             return self.impl
-        platform = jax.devices()[0].platform
-        return "pallas" if platform == "tpu" else "xla"
+        return "pallas" if on_tpu() else "xla"
 
 
 _CONFIG = KernelConfig()
@@ -46,42 +55,55 @@ def get_kernel_config() -> KernelConfig:
     return _CONFIG
 
 
+def _use_groupby_kernel(cfg, values, num_groups: int, words: int) -> bool:
+    width = 1 if values.ndim == 1 else values.shape[1]
+    return ((cfg or _CONFIG).resolved() == "pallas" and
+            words * width * max(num_groups, 1) * LANES * 4
+            <= GROUPBY_VMEM_BYTES)
+
+
 def groupby_sum(codes, values, num_groups: int, cfg: KernelConfig | None = None):
-    cfg = cfg or _CONFIG
-    if cfg.resolved() == "pallas":
-        return _groupby_sum_pallas(codes, values, num_groups,
-                                   interpret=cfg.interpret)
+    """f32 sums of floats; int32 sums of ints and bools (wrapping past
+    2^31: use ``groupby_sum_words`` where a total may not fit)."""
+    if _use_groupby_kernel(cfg, values, num_groups,
+                           1 if values.dtype.kind == "f" else 2):
+        return _groupby_sum_pallas(codes, values, num_groups)
     return ref.groupby_sum_ref(codes, values, num_groups)
 
 
+def groupby_sum_words(codes, values, num_groups: int,
+                      cfg: KernelConfig | None = None):
+    """Exact sums of int or bool values as int32 words ``(hi, lo)``;
+    ``words_to_int64`` gives the totals."""
+    if _use_groupby_kernel(cfg, values, num_groups, 2):
+        return _groupby_sum_words_pallas(codes, values, num_groups)
+    return ref.groupby_sum_words_ref(codes, values, num_groups)
+
+
+def words_to_int64(hi, lo) -> np.ndarray:
+    """Host int64 totals of ``groupby_sum_words`` words (``lo`` may exceed
+    its 16 bits after words are added across shards)."""
+    return (np.asarray(hi).astype(np.int64) << WORD_BITS) + np.asarray(lo)
+
+
 def filter_compact(values, mask, cfg: KernelConfig | None = None):
+    (out,), count = filter_compact_columns((values,), mask, cfg)
+    return out, count
+
+
+def filter_compact_columns(columns, mask, cfg: KernelConfig | None = None):
+    """Stable compaction of each (N,) column under one mask → (packed
+    columns, count); slots ≥ count are zeroed."""
     cfg = cfg or _CONFIG
     if cfg.resolved() == "pallas":
-        return _filter_compact_pallas(values, mask, interpret=cfg.interpret)
-    return ref.filter_compact_ref(values, mask)
+        return _compact_pallas(columns, mask)
+    return _compact_xla(tuple(columns), mask)
 
 
-def filter_compact_chunked(values, mask, chunk: int = 1 << 20,
-                           cfg: KernelConfig | None = None):
-    """Two-level compaction for arrays beyond VMEM residency: compact each
-    chunk, then compact the concatenated survivors' prefix mask."""
-    n = values.shape[0]
-    if n <= chunk:
-        return filter_compact(values, mask, cfg)
-    packed_parts, counts = [], []
-    for lo in range(0, n, chunk):
-        p, c = filter_compact(values[lo:lo + chunk], mask[lo:lo + chunk], cfg)
-        packed_parts.append(p)
-        counts.append(c)
-    packed = jnp.concatenate(packed_parts)
-    counts = jnp.stack(counts)
-    # validity mask of the concatenated chunks, then one more compaction
-    sizes = jnp.asarray([p.shape[0] for p in packed_parts])
-    offs = jnp.cumsum(sizes) - sizes
-    idx = jnp.arange(packed.shape[0])
-    chunk_id = jnp.searchsorted(offs, idx, side="right") - 1
-    valid = (idx - offs[chunk_id]) < counts[chunk_id]
-    return filter_compact(packed, valid, cfg)
+@jax.jit
+def _compact_xla(columns, mask):
+    return (tuple(ref.filter_compact_ref(v, mask)[0] for v in columns),
+            ref.filter_count_ref(mask))
 
 
 def zonemap(values, block_rows: int = 4096, cfg: KernelConfig | None = None):
@@ -91,6 +113,5 @@ def zonemap(values, block_rows: int = 4096, cfg: KernelConfig | None = None):
         # would otherwise emit one identity-padded block)
         return (jnp.zeros((0,), values.dtype), jnp.zeros((0,), values.dtype))
     if cfg.resolved() == "pallas":
-        return _zonemap_pallas(values, block_rows=block_rows,
-                               interpret=cfg.interpret)
+        return _zonemap_pallas(values, block_rows=block_rows)
     return ref.zonemap_ref(values, block_rows)

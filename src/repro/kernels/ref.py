@@ -11,8 +11,11 @@ import jax.numpy as jnp
 def groupby_sum_ref(codes: jnp.ndarray, values: jnp.ndarray,
                     num_groups: int) -> jnp.ndarray:
     """Segment-sum of ``values`` (N,) or (N, V) by int ``codes`` (N,) into
-    (G,) or (G, V).  Out-of-range codes contribute nothing."""
+    (G,) or (G, V), accumulated in f32 for floats and int32 for ints and
+    bools.  Out-of-range codes contribute nothing."""
     import jax
+    values = values.astype(jnp.float32 if values.dtype.kind == "f"
+                           else jnp.int32)
     valid = (codes >= 0) & (codes < num_groups)
     safe = jnp.where(valid, codes, num_groups)
     if values.ndim == 1:
@@ -20,6 +23,39 @@ def groupby_sum_ref(codes: jnp.ndarray, values: jnp.ndarray,
         return jax.ops.segment_sum(vals, safe, num_groups + 1)[:num_groups]
     vals = jnp.where(valid[:, None], values, 0)
     return jax.ops.segment_sum(vals, safe, num_groups + 1)[:num_groups]
+
+
+def groupby_sum_words_ref(codes: jnp.ndarray, values: jnp.ndarray,
+                          num_groups: int, chunk: int = 1 << 14
+                          ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Exact segment sums of int or bool ``values`` as int32 words
+    ``(hi, lo)``, sum = ``hi · 2^16 + lo``, ``0 <= lo < 2^16``.  Rows are
+    summed ``chunk`` at a time so a chunk's ``lo`` partial (< chunk · 2^16)
+    cannot wrap before its carry moves into ``hi``."""
+    import jax
+    squeeze = values.ndim == 1
+    values = values.astype(jnp.int32)
+    if squeeze:
+        values = values[:, None]
+    n, v = values.shape
+    valid = (codes >= 0) & (codes < num_groups)
+    safe = jnp.where(valid, codes, num_groups).astype(jnp.int32)
+    pad = -n % chunk
+    safe = jnp.pad(safe, (0, pad), constant_values=num_groups).reshape(
+        -1, chunk)
+    values = jnp.pad(values, ((0, pad), (0, 0))).reshape(-1, chunk, v)
+
+    def step(acc, xs):
+        hi, lo = acc
+        k, x = xs
+        lo = lo + jax.ops.segment_sum(x & 0xFFFF, k, num_groups + 1)
+        hi = hi + jax.ops.segment_sum(x >> 16, k, num_groups + 1) + (lo >> 16)
+        return (hi, lo & 0xFFFF), None
+
+    zeros = jnp.zeros((num_groups + 1, v), jnp.int32)
+    (hi, lo), _ = jax.lax.scan(step, (zeros, zeros), (safe, values))
+    hi, lo = hi[:num_groups], lo[:num_groups]
+    return (hi[:, 0], lo[:, 0]) if squeeze else (hi, lo)
 
 
 def filter_count_ref(mask: jnp.ndarray) -> jnp.ndarray:

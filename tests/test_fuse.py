@@ -134,8 +134,9 @@ def test_fingerprint_covers_fusion_flag_and_kernel_impl(rng):
 # Execution: the fused pass must equal the op-at-a-time members
 
 
-@pytest.mark.parametrize("xp_name", ("numpy", "jnp"))
+@pytest.mark.parametrize("xp_name", ("numpy", "jnp", "jnp-pallas"))
 def test_fused_execution_matches_sequential(rng, xp_name):
+    # "jnp-pallas": the TPU kernel impl, whose compaction stays on device
     node = _chain(_frame(rng))._node
     roots, _ = fuse_rowwise_chains([node])
     (fused,) = _fused_nodes(roots)
@@ -145,12 +146,13 @@ def test_fused_execution_matches_sequential(rng, xp_name):
         "c": rng.integers(0, 3, 300).astype(np.float64),
     }
     cols["b"][::7] = np.nan
-    if xp_name == "jnp":
+    if xp_name != "numpy":
         import jax.numpy as jnp
         table = {k: jnp.asarray(v) for k, v in cols.items()}
     else:
         table = dict(cols)
-    got = X.apply_fused_rowwise(table, fused.ops)
+    got = X.apply_fused_rowwise(
+        table, fused.ops, impl="pallas" if xp_name == "jnp-pallas" else None)
     ref = dict(table)
     for m in fused.ops:
         ref = X.rowwise._apply_member(ref, m)

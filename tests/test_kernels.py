@@ -1,5 +1,6 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (ref.py),
-running the Pallas kernels in interpret mode (TPU-target BlockSpecs)."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (ref.py).
+The Pallas kernels run in interpret mode on the CPU and compiled on a
+TPU (the mode follows the platform)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,7 +21,7 @@ def test_groupby_sum_sweep(rng, n, g, vdim):
     else:
         vals = rng.normal(size=(n, vdim)).astype(np.float32)
     got = groupby_sum(jnp.asarray(codes), jnp.asarray(vals), g,
-                      block_rows=256)
+                      block_rows=1024)
     want = ref.groupby_sum_ref(jnp.asarray(codes), jnp.asarray(vals), g)
     # blocked vs flat accumulation order → f32 rounding differences
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -53,7 +54,7 @@ def test_filter_compact_sweep(rng, n, p):
     vals = rng.normal(size=n).astype(np.float32)
     mask = rng.random(n) < p
     got, cnt = filter_compact(jnp.asarray(vals), jnp.asarray(mask),
-                              block_rows=128)
+                              block_rows=1024)
     want, wcnt = ref.filter_compact_ref(jnp.asarray(vals), jnp.asarray(mask))
     assert int(cnt) == int(wcnt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
@@ -68,15 +69,16 @@ def test_zonemap_sweep(rng, n, block):
     np.testing.assert_allclose(np.asarray(mx), np.asarray(rmx))
 
 
-def test_chunked_compaction_large(rng):
+def test_filter_compact_large_whole_column(rng):
+    # many grid steps and many row tiles in one call: the row stitch must
+    # carry survivors across block boundaries in order
     vals = rng.normal(size=100_000).astype(np.float32)
     mask = rng.random(100_000) < 0.2
-    got, cnt = ops.filter_compact_chunked(
-        jnp.asarray(vals), jnp.asarray(mask), chunk=1 << 14,
-        cfg=ops.KernelConfig(impl="pallas"))
+    got, cnt = filter_compact(jnp.asarray(vals), jnp.asarray(mask),
+                              block_rows=8192)
     assert int(cnt) == int(mask.sum())
-    np.testing.assert_allclose(np.asarray(got)[: int(cnt)], vals[mask],
-                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got)[: int(cnt)], vals[mask])
+    assert not np.asarray(got)[int(cnt):].any()
 
 
 def test_kernel_config_dispatch():
@@ -87,7 +89,10 @@ def test_kernel_config_dispatch():
     x = np.asarray(ops.groupby_sum(codes, vals, 2, cfg_x))
     p = np.asarray(ops.groupby_sum(codes, vals, 2, cfg_p))
     np.testing.assert_allclose(x, p, rtol=1e-6)
-    assert ops.KernelConfig(impl="auto").resolved() == "xla"  # CPU host
+    # "auto" picks the Pallas kernels exactly on a TPU host
+    from repro.kernels.platform import on_tpu
+    assert ops.KernelConfig(impl="auto").resolved() == (
+        "pallas" if on_tpu() else "xla")
 
 
 # ---------------------------------------------------------------------------
@@ -181,3 +186,62 @@ def test_zonemap_non_block_multiple(rng, cfg, n):
     # global reduction matches the raw column (partition-skip contract)
     assert np.asarray(mn).min() == np.asarray(vals).min()
     assert np.asarray(mx).max() == np.asarray(vals).max()
+
+
+# ---------------------------------------------------------------------------
+# Int columns above 2^24 (epoch seconds, ids): f32 holds them only to within
+# 128, so every kernel must keep ints as ints — values, bounds and sums
+# exact, dtype unchanged — on both impls.
+
+_EPOCH_LO, _EPOCH_HI = 1_577_836_800, 1_609_459_200
+
+
+@pytest.mark.parametrize("cfg", _IMPLS, ids=_IMPL_IDS)
+@pytest.mark.parametrize("dtype", [np.int32, np.uint32])
+def test_filter_compact_int_above_2_24(rng, cfg, dtype):
+    vals = rng.integers(_EPOCH_LO, _EPOCH_HI, 3000).astype(dtype)
+    mask = rng.random(3000) < 0.5
+    got, cnt = ops.filter_compact(jnp.asarray(vals), jnp.asarray(mask), cfg)
+    assert got.dtype == dtype
+    assert int(cnt) == int(mask.sum())
+    np.testing.assert_array_equal(np.asarray(got)[: int(cnt)], vals[mask])
+
+
+@pytest.mark.parametrize("cfg", _IMPLS, ids=_IMPL_IDS)
+def test_zonemap_int_above_2_24(rng, cfg):
+    vals = rng.integers(_EPOCH_LO, _EPOCH_HI, 5000).astype(np.int32)
+    mn, mx = ops.zonemap(jnp.asarray(vals), 1024, cfg)
+    rmn, rmx = ref.zonemap_ref(jnp.asarray(vals), 1024)
+    assert mn.dtype == mx.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(mn), np.asarray(rmn))
+    np.testing.assert_array_equal(np.asarray(mx), np.asarray(rmx))
+    assert np.asarray(mn).min() == vals.min()
+    assert np.asarray(mx).max() == vals.max()
+
+
+@pytest.mark.parametrize("cfg", _IMPLS, ids=_IMPL_IDS)
+@pytest.mark.parametrize("n", [150, 200_000])   # one grid step, four
+def test_groupby_sum_words_above_2_31(rng, cfg, n):
+    # group totals far outside int32, of both signs: the (hi, lo) words
+    # must carry every step's low-word overflow and give them exactly
+    codes = rng.integers(0, 3, n).astype(np.int32)
+    vals = rng.integers(1 << 30, (1 << 31) - 1, n).astype(np.int32)
+    vals[codes == 1] = -vals[codes == 1]
+    got = ops.words_to_int64(*ops.groupby_sum_words(
+        jnp.asarray(codes), jnp.asarray(vals), 3, cfg))
+    want = np.zeros(3, np.int64)
+    np.add.at(want, codes, vals.astype(np.int64))
+    assert abs(want).min() > 1 << 31
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("cfg", _IMPLS, ids=_IMPL_IDS)
+def test_groupby_sum_int_above_2_24(rng, cfg):
+    # per-group sums near 2^30: exact only if the kernel sums in int32
+    codes = rng.integers(0, 3, 150).astype(np.int32)
+    vals = rng.integers(0, 1 << 20, 150).astype(np.int32) + (1 << 24) + 1
+    got = ops.groupby_sum(jnp.asarray(codes), jnp.asarray(vals), 3, cfg)
+    want = np.bincount(codes, weights=vals.astype(np.float64), minlength=3)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got).astype(np.int64),
+                                  want.astype(np.int64))

@@ -216,6 +216,28 @@ def test_distributed_backend_join_sort_distinct_native(rng, monkeypatch):
     _assert_tables_equal(res, ref)
 
 
+@pytest.mark.parametrize("engine", ["eager", "distributed"])
+def test_int_group_sums_past_int32_are_exact(rng, engine):
+    """Group totals of an int column above 2^31 come back exact, as the
+    int64 pandas gives, though device ints are int32."""
+    from repro.core.backends import get_backend
+    n = 3000
+    arrays = {"k": rng.integers(0, 4, n).astype(np.int64),
+              "cents": rng.integers(1 << 29, 1 << 31, n).astype(np.int64)}
+    arrays["cents"][arrays["k"] == 3] = 7          # one group fits int32
+    node = G.GroupByAgg(G.Scan(core.InMemorySource(arrays, 512)), ["k"],
+                        {"total": ("cents", "sum"), "avg": ("cents", "mean")})
+    res = get_backend(engine).execute([node], get_context())[node.id]
+    want = np.zeros(4, np.int64)
+    np.add.at(want, arrays["k"], arrays["cents"])
+    assert want.max() > 1 << 31
+    np.testing.assert_array_equal(np.asarray(res["k"]), np.arange(4))
+    np.testing.assert_array_equal(np.asarray(res["total"]), want)
+    assert np.asarray(res["total"]).dtype == np.int64
+    np.testing.assert_allclose(np.asarray(res["avg"], np.float64),
+                               want / np.bincount(arrays["k"]), rtol=1e-4)  # f32 sums
+
+
 # ---------------------------------------------------------------------------
 # Device-resident handoff: distributed→distributed chain, no host gather
 

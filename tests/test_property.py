@@ -45,8 +45,8 @@ def pipeline_ops(draw):
 
 
 def _apply_ops(df, ops, renamed):
-    for kind, col, val in ops:
-        col = renamed.get(col, col)
+    for kind, name, val in ops:
+        col = renamed.get(name, name)     # ops name the original columns
         if kind == "filter_gt":
             df = df[df[col] > val]
         elif kind == "filter_lt":
@@ -60,7 +60,7 @@ def _apply_ops(df, ops, renamed):
         elif kind == "rename":
             new = f"{col}_r"
             df = df.rename({col: new})
-            renamed[col] = new
+            renamed[name] = new
     return df
 
 
@@ -271,7 +271,7 @@ def test_filter_compact_properties(mask, seed):
     mask = np.asarray(mask)
     vals = rng.normal(size=mask.shape[0]).astype(np.float32)
     packed, count = filter_compact(jnp.asarray(vals), jnp.asarray(mask),
-                                   block_rows=64)
+                                   block_rows=1024)
     packed = np.asarray(packed)
     assert int(count) == int(mask.sum())
     np.testing.assert_allclose(packed[: int(count)], vals[mask], rtol=1e-6)
@@ -288,7 +288,7 @@ def test_groupby_sum_kernel_total_preserved(groups, n, seed):
     codes = rng.integers(0, groups, n).astype(np.int32)
     vals = rng.normal(size=n).astype(np.float32)
     out = np.asarray(groupby_sum(jnp.asarray(codes), jnp.asarray(vals),
-                                 groups, block_rows=64))
+                                 groups, block_rows=1024))
     np.testing.assert_allclose(out.sum(), vals.sum(), rtol=1e-4, atol=1e-4)
 
 

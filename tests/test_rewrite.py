@@ -99,6 +99,22 @@ def test_filter_pushes_through_concat(rng):
     assert all(c.op == "filter" for c in root.inputs)
 
 
+def test_filter_through_concat_keeps_shared_scan_whole(rng):
+    # after the push, the scan feeds both the filter and a head: zone maps
+    # must not prune partitions that the head still reads
+    b = np.zeros(64)
+    b[50] = 3.0                      # the only match, in the second partition
+    table = {"a": np.arange(64), "b": b}
+    res = {}
+    for flag in (True, False):
+        with rpd.session(engine="eager", rewrites=flag):
+            df = rpd.from_arrays(table, partition_rows=32)
+            cat = rpd.concat([df, df.head(20)])
+            res[flag] = np.asarray(cat[cat["b"] > 2].compute().columns["a"])
+    np.testing.assert_array_equal(res[True], [50])
+    np.testing.assert_array_equal(res[False], [50])
+
+
 def test_map_rows_vectorizes_to_native_exprs(rng):
     df = _frame(rng)
     node = df.apply_rows(lambda t: {"a": t["a"], "s": t["a"] + 2 * t["b"]},

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from repro.ckpt.checkpoint import CheckpointManager
-from repro.compat import shard_map
 from repro.configs import get_config
 from repro.data.pipeline import (PipelineConfig, TokenPipeline,
                                  synthetic_token_source)
@@ -173,8 +172,8 @@ def test_compressed_psum_shardmap():
 
     @partial(jax.jit)
     def run(x):
-        f = shard_map(lambda v: compressed_psum(v[0], "d")[0][None],
-                      mesh=mesh, in_specs=jax.sharding.PartitionSpec("d"),
+        f = jax.shard_map(lambda v: compressed_psum(v[0], "d")[0][None],
+                          mesh=mesh, in_specs=jax.sharding.PartitionSpec("d"),
                           out_specs=jax.sharding.PartitionSpec("d"))
         return f(x[None])
     out = run(x)[0]
