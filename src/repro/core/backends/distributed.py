@@ -38,6 +38,7 @@ from .. import graph as G
 from .. import physical as X
 from ..context import LaFPContext
 from ..physical.sharded import ShardedTable
+from ...obs.spans import engine_span
 from .eager import EagerBackend
 
 _DIST_OPS = ("scan", "filter", "project", "assign", "rename", "astype",
@@ -143,7 +144,7 @@ class DistributedBackend:
         if isinstance(n, G.Length):
             child = self._eval(n.inputs[0], memo)
             if isinstance(child, ShardedTable):
-                return int(jnp.sum(child.valid))
+                return child.rows()
             return self._fallback_node(n, [child])
         if isinstance(n, G.GroupByAgg):
             child = self._eval(n.inputs[0], memo)
@@ -212,12 +213,18 @@ class DistributedBackend:
         tracer = getattr(ctx, "tracer", None)
         if metrics is not None and n.skip_partitions:
             metrics.inc("io.partitions_pruned", len(n.skip_partitions))
-        parts = [load_scan_partition(n, pi, metrics=metrics, tracer=tracer)
-                 for pi in scan_partition_indices(n)]
-        if not parts:
-            parts = [empty_scan_table(n)]
-        full = {c: np.concatenate([p[c] for p in parts]) for c in parts[0]}
-        return X.shard_host_table(full, self.mesh, self.axis)
+        # the scan's own time, outside its partitions' ``io`` spans and the
+        # sharding, is the concatenation of the partitions
+        with engine_span("operator", "scan", tracer=tracer,
+                         source=n.source.name):
+            parts = [load_scan_partition(n, pi, metrics=metrics,
+                                         tracer=tracer)
+                     for pi in scan_partition_indices(n)]
+            if not parts:
+                parts = [empty_scan_table(n)]
+            full = {c: np.concatenate([p[c] for p in parts])
+                    for c in parts[0]}
+            return X.shard_host_table(full, self.mesh, self.axis)
 
     def _rowwise_sharded(self, n: G.Node, t: ShardedTable) -> ShardedTable:
         if isinstance(n, G.Filter):
@@ -309,9 +316,11 @@ class DistributedBackend:
 
         out = run(col if col is not None else None, valid)
         if fn == "mean":
-            return float(out[0] / jnp.maximum(out[1], 1))
+            with engine_span("sync", "reduce"):
+                return float(out[0] / jnp.maximum(out[1], 1))
         if fn == "count":
-            return int(out)
+            with engine_span("sync", "reduce"):
+                return int(out)
         return out
 
     def _try_groupby_sharded(self, n: G.GroupByAgg, t: ShardedTable):
@@ -322,7 +331,8 @@ class DistributedBackend:
         karr = t.cols.get(key)
         if karr is None or karr.dtype.kind not in "iu":
             return None
-        kmax = int(jnp.max(jnp.where(t.valid, karr, 0)))
+        with engine_span("sync", "group_domain"):
+            kmax = int(jnp.max(jnp.where(t.valid, karr, 0)))
         G_dom = kmax + 1
         if G_dom > 1 << 16:
             return None
@@ -388,14 +398,14 @@ class DistributedBackend:
         from ...kernels import ops as K
         vals = {c: value_cols[c] for c in sorted(value_cols)}
         outs = run(karr, t.valid, vals, G_dom)
-        cnt = np.asarray(outs["__count"][:G_dom])
+        cnt = X.host_array(outs["__count"][:G_dom], "groupby_agg")
         groups = np.nonzero(cnt > 0)[0]
         result = {key: groups.astype(np.dtype(karr.dtype))}
         for out_name, (_c, fn) in n.aggs.items():
             arr = outs[out_name]
             # int sums come back as (hi, lo) words, exact in int64 here
             arr = (K.words_to_int64(arr[0], arr[1]) if arr.ndim == 2
-                   else np.asarray(arr))[:G_dom]
+                   else X.host_array(arr, "groupby_agg"))[:G_dom]
             if fn == "mean":
                 result[out_name] = (arr / np.maximum(cnt, 1))[groups]
             elif fn == "count":

@@ -14,6 +14,7 @@ import numpy as np
 from .. import physical as X
 from .. import graph as G
 from ..context import LaFPContext
+from ...obs.spans import engine_span
 
 
 class EagerBackend:
@@ -32,20 +33,27 @@ class EagerBackend:
         tracer = getattr(ctx, "tracer", None)
         if metrics is not None and n.skip_partitions:
             metrics.inc("io.partitions_pruned", len(n.skip_partitions))
-        parts = [load_scan_partition(n, pi, metrics=metrics, tracer=tracer)
-                 for pi in scan_partition_indices(n)]
-        if not parts:
-            return empty_scan_table(n)
-        table = {c: np.concatenate([p[c] for p in parts]) for c in parts[0]}
-        if self.device_arrays:
-            table = X.to_jax(table)
-        return table
+        # the scan's own time, outside its partitions' ``io`` spans and its
+        # upload, is the concatenation of the partitions
+        with engine_span("operator", "scan", tracer=tracer,
+                         source=n.source.name):
+            parts = [load_scan_partition(n, pi, metrics=metrics,
+                                         tracer=tracer)
+                     for pi in scan_partition_indices(n)]
+            if not parts:
+                return empty_scan_table(n)
+            table = {c: np.concatenate([p[c] for p in parts])
+                     for c in parts[0]}
+            if self.device_arrays:
+                table = X.to_jax(table, "scan")
+            return table
 
     def eval_node(self, n: G.Node, vals: list[Any], ctx: LaFPContext):
         if isinstance(n, G.Handoff):
             return X.handoff_value(n, self.device_arrays)
         if isinstance(n, G.Materialized):
-            return (X.to_jax(n.table) if self.device_arrays else n.table)
+            return (X.to_jax(n.table, "materialized") if self.device_arrays
+                    else n.table)
         if isinstance(n, G.Scan):
             return self._load_scan(n, ctx)
         if isinstance(n, G.Filter):
@@ -125,7 +133,7 @@ class EagerBackend:
                 key = getattr(n, "cache_key", None) or n.key()
                 val = results[n.id]
                 if isinstance(val, dict):
-                    val = X.to_numpy(val)      # cache host-side
+                    val = X.to_numpy(val, "persist")   # cache host-side
                 ctx.persist_cache[key] = val
             # paper §2.6: free inputs whose consumers are all done
             for i in n.inputs:

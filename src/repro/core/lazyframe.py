@@ -9,6 +9,7 @@ import numpy as np
 
 from . import expr as E
 from . import graph as G
+from . import physical as X
 from .context import get_context
 from .source import InMemorySource, Source
 
@@ -116,7 +117,8 @@ class LazyColumn:
         return series_fallback(self, name)
 
     def to_numpy(self):
-        return np.asarray(self.compute(force_reason="Series.to_numpy"))
+        return X.host_array(self.compute(force_reason="Series.to_numpy"),
+                            "result")
 
     @property
     def values(self):
@@ -226,10 +228,10 @@ class LazyScalar:
         return self.__format__("")
 
     def __float__(self):
-        return float(self.compute())
+        return float(X.host_array(self.compute(), "result"))
 
     def __int__(self):
-        return int(self.compute())
+        return int(X.host_array(self.compute(), "result"))
 
 
 class GroupBy:
@@ -457,7 +459,7 @@ class LazyFrame:
 
     def to_numpy_table(self, live_df=None):
         res = self.compute(live_df)
-        return {k: np.asarray(v) for k, v in res.columns.items()}
+        return X.to_numpy(res.columns, "result")
 
     def __len__(self):
         return int(_execute([G.Length(self._node)], None, "len")[0])
@@ -548,7 +550,7 @@ class Result:
         return self.columns[k]
 
     def decode(self, col: str):
-        codes = np.asarray(self.columns[col])
+        codes = X.host_array(self.columns[col], "result")
         vocab = self.vocab[col]
         return np.asarray([vocab[c] for c in codes], dtype=object)
 
@@ -559,11 +561,13 @@ class Result:
         lines = [f"<Result {n} rows [{cols}]>"]
         show = min(n, 10)
         names = list(self.columns)
+        shown = X.to_numpy({c: v[:show] for c, v in self.columns.items()},
+                           "result")
         lines.append(" | ".join(f"{x:>12}" for x in names))
         for i in range(show):
             vals = []
             for c in names:
-                v = self.columns[c][i]
+                v = shown[c][i]
                 if c in self.vocab:
                     v = self.vocab[c][int(v)]
                 vals.append(f"{v!s:>12.12}")

@@ -25,8 +25,9 @@ Module map
 """
 from __future__ import annotations
 
-from .table import (Table, apply_concat, handoff_value, is_jax, table_nbytes,
-                    table_rows, to_host_value, to_jax, to_numpy, xp_of)
+from .table import (Table, apply_concat, handoff_value, host_array, is_jax,
+                    table_nbytes, table_rows, to_host_value, to_jax, to_numpy,
+                    xp_of)
 from .rowwise import (apply_assign, apply_astype, apply_fillna, apply_filter,
                       apply_fused_rowwise, apply_head, apply_map_rows,
                       apply_project, apply_rename)
@@ -41,7 +42,7 @@ from .sharded import (BROADCAST_BUILD_BYTES, ShardedTable, shard_host_table,
 
 __all__ = [
     "Table", "is_jax", "xp_of", "table_rows", "table_nbytes", "to_numpy",
-    "to_jax", "to_host_value", "handoff_value", "apply_concat",
+    "to_jax", "host_array", "to_host_value", "handoff_value", "apply_concat",
     "apply_filter", "apply_project", "apply_assign", "apply_rename",
     "apply_astype", "apply_fillna", "apply_fused_rowwise", "apply_head",
     "apply_map_rows",

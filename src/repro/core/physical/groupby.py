@@ -15,14 +15,17 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .table import Table, is_jax
-from ...obs.spans import traced_op
+from .table import Table, is_jax, to_jax, to_numpy
+from ...obs.spans import engine_span, traced_op
 
 
 def _factorize(arr):
-    """codes, uniques — order of uniques is sorted-value order."""
+    """codes, uniques — order of uniques is sorted-value order.  On the
+    device the number of uniques is read back before the codes exist: a
+    sync."""
     if is_jax(arr):
-        uniques, codes = jnp.unique(arr, return_inverse=True)
+        with engine_span("sync", "factorize"):
+            uniques, codes = jnp.unique(arr, return_inverse=True)
     else:
         uniques, codes = np.unique(arr, return_inverse=True)
     return codes, uniques
@@ -66,7 +69,8 @@ def apply_groupby_agg(table: Table, keys: Sequence[str],
     layer (``repro.kernels.ops.groupby_sum``)."""
     combined, decode = _factorize_multi(table, list(keys))
     if is_jax(combined):
-        groups, inv = jnp.unique(combined, return_inverse=True)
+        with engine_span("sync", "factorize"):
+            groups, inv = jnp.unique(combined, return_inverse=True)
         num = int(groups.shape[0])
         out = decode(groups)
         for out_name, (col, fn) in aggs.items():
@@ -74,7 +78,7 @@ def apply_groupby_agg(table: Table, keys: Sequence[str],
         if not all(is_jax(v) for v in out.values()):
             # an int sum left int32: the (num-row) result moves to the
             # host, where its int64 column can live
-            out = {k: np.asarray(v) for k, v in out.items()}
+            out = to_numpy(out, "int_sum")
     else:
         groups, inv = np.unique(combined, return_inverse=True)
         num = int(groups.shape[0])
@@ -101,7 +105,7 @@ def _segment_agg_jax(table, col, fn, seg_ids, num):
         info = np.iinfo(np.int32)
         if total.size and (total.min() < info.min or total.max() > info.max):
             return total
-        return jnp.asarray(total.astype(np.int32))
+        return to_jax({"sum": total.astype(np.int32)}, "int_sum")["sum"]
     if fn == "mean":
         s = K.groupby_sum(seg_ids, vals.astype(jnp.float32), num)
         c = K.groupby_sum(seg_ids, ones, num)
@@ -113,7 +117,8 @@ def _segment_agg_jax(table, col, fn, seg_ids, num):
     if fn == "nunique":
         sub_codes, _ = _factorize(vals)
         pair = seg_ids.astype(jnp.int64) * (jnp.max(sub_codes) + 1) + sub_codes
-        uniq_pairs = jnp.unique(pair)
+        with engine_span("sync", "nunique"):
+            uniq_pairs = jnp.unique(pair)
         seg_of_pair = uniq_pairs // (jnp.max(sub_codes) + 1)
         return jax.ops.segment_sum(jnp.ones_like(seg_of_pair), seg_of_pair, num)
     raise ValueError(f"unknown agg fn {fn}")

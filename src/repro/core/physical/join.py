@@ -19,7 +19,7 @@ from ...obs.spans import traced_op
 @traced_op("join")
 def apply_join(left: Table, right: Table, on: Sequence[str], how="inner",
                suffixes=("_x", "_y")) -> Table:
-    lj, rj = to_numpy(left), to_numpy(right)
+    lj, rj = to_numpy(left, "join"), to_numpy(right, "join")
     was_jax = xp_of(left) is jnp
     lkeys, _ = _factorize_multi_np_pair(lj, rj, on)
     lcode, rcode = lkeys
@@ -68,7 +68,7 @@ def apply_join(left: Table, right: Table, on: Sequence[str], how="inner",
             col = np.where(r_idx >= 0, col, np.nan)
         out[name] = col
     if was_jax:
-        out = to_jax(out)
+        out = to_jax(out, "join")
     return out
 
 

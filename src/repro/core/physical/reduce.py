@@ -7,7 +7,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .table import Table, table_rows, xp_of
-from ...obs.spans import traced_op
+from ...obs.spans import NOOP_SPAN, engine_span, traced_op
 
 
 @traced_op("reduce")
@@ -27,7 +27,8 @@ def apply_reduce(table: Table, column: str | None, fn: str):
     if fn == "max":
         return xp.max(vals)
     if fn == "nunique":
-        return int(xp.unique(vals).shape[0])
+        with (engine_span("sync", "nunique") if xp is jnp else NOOP_SPAN):
+            return int(xp.unique(vals).shape[0])
     if fn == "median":
         # pandas skipna semantics; float64 on host like mean (jnp computes
         # in its native f32 precision)

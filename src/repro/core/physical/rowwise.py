@@ -8,8 +8,8 @@ from typing import Mapping, Sequence
 
 import numpy as np
 
-from .table import Table, table_rows, xp_of
-from ...obs.spans import traced_op
+from .table import Table, host_array, table_rows, to_jax, to_numpy, xp_of
+from ...obs.spans import engine_span, traced_op
 
 
 @traced_op("filter")
@@ -207,14 +207,15 @@ def apply_fused_rowwise(table: Table, ops, impl: str | None = None) -> Table:
         from ...kernels import ops as K
         packed, count = K.filter_compact_columns(
             tuple(cols.values()), mask, K.KernelConfig(impl="xla"))
-        k = int(count)
+        with engine_span("sync", "compact"):
+            k = int(count)
         return {c: v[:k] for c, v in zip(cols, packed)}
     # xla hosts: jax's eager dynamic gather re-dispatches per column and
     # loses badly to one host boolean gather; arrays round-trip through
     # numpy (near zero-copy on CPU) and come back device-resident
-    import jax.numpy as jnp
-    host_mask = np.asarray(mask)
-    return {c: jnp.asarray(np.asarray(v)[host_mask]) for c, v in cols.items()}
+    host_mask = host_array(mask, "compact")
+    return to_jax({c: v[host_mask]
+                   for c, v in to_numpy(cols, "compact").items()}, "compact")
 
 
 @traced_op("map_rows")

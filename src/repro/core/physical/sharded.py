@@ -38,13 +38,23 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .join import apply_join
 from .sort import apply_drop_duplicates
-from ...obs.spans import metric_inc, traced_op
+from .table import device_nbytes, to_numpy
+from ...obs.spans import engine_span, metric_inc, traced_op
 
 # build sides at or below this many bytes replicate to every shard
 # (broadcast-hash join); larger builds go through the shuffle exchange
 BROADCAST_BUILD_BYTES = 4 << 20
 
 _ROWID = "__lafp_rowid"
+_VALID = "__lafp_valid"     # the validity mask, carried beside the columns
+_CODES = "__lafp_codes"     # the build side's sorted key codes, likewise
+
+
+def _put(arrays: dict, sharding, site: str) -> dict:
+    """``jax.device_put`` of host arrays under one h2d transfer."""
+    moved = sum(device_nbytes(v) for v in arrays.values())
+    with engine_span("transfer", site, dir="h2d", bytes=moved):
+        return {k: jax.device_put(v, sharding) for k, v in arrays.items()}
 
 
 class ShardedTable:
@@ -60,14 +70,16 @@ class ShardedTable:
 
     def rows(self) -> int:
         """Valid (unpadded) row count across all shards."""
-        return int(jnp.sum(self.valid))
+        with engine_span("sync", "rows"):
+            return int(jnp.sum(self.valid))
 
     def nbytes(self) -> int:
         return sum(int(v.nbytes) for v in self.cols.values())
 
     def gather(self) -> dict[str, np.ndarray]:
-        mask = np.asarray(self.valid).reshape(-1)
-        return {k: np.asarray(v).reshape(-1)[mask] for k, v in self.cols.items()}
+        host = to_numpy(dict(self.cols, **{_VALID: self.valid}), "gather")
+        mask = host.pop(_VALID).reshape(-1)
+        return {k: v.reshape(-1)[mask] for k, v in host.items()}
 
 
 @traced_op("sharded_head")
@@ -98,14 +110,14 @@ def shard_host_table(full: dict[str, np.ndarray], mesh, axis: str
     per = -(-max(rows, 1) // S)
     pad = S * per - rows
     valid = np.arange(S * per) < rows
-    sharding = NamedSharding(mesh, P(axis))
-    cols = {}
+    host = {}
     for c, v in full.items():
         v = np.asarray(v)
         vp = np.concatenate([v, np.zeros(pad, v.dtype)]) if pad else v
-        cols[c] = jax.device_put(vp.reshape(S, per), sharding)
-    vmask = jax.device_put(valid.reshape(S, per), sharding)
-    return ShardedTable(cols, vmask)
+        host[c] = vp.reshape(S, per)
+    host[_VALID] = valid.reshape(S, per)
+    cols = _put(host, NamedSharding(mesh, P(axis)), "shard")
+    return ShardedTable(cols, cols.pop(_VALID))
 
 
 def _host_shards(t: ShardedTable) -> tuple[list[dict], list[np.ndarray], int]:
@@ -113,8 +125,8 @@ def _host_shards(t: ShardedTable) -> tuple[list[dict], list[np.ndarray], int]:
 
     Global row id == position in ``gather()`` order, so restoring ascending
     row-id order after an exchange reproduces the pre-exchange row order."""
-    cols = {k: np.asarray(v) for k, v in t.cols.items()}
-    valid = np.asarray(t.valid)
+    cols = to_numpy(dict(t.cols, **{_VALID: t.valid}), "exchange")
+    valid = cols.pop(_VALID)
     parts, rowids = [], []
     offset = 0
     for s in range(valid.shape[0]):
@@ -134,18 +146,19 @@ def _restack(parts: list[dict[str, np.ndarray]], mesh, axis: str,
     assert len(parts) == S, (len(parts), S)
     lens = [len(next(iter(p.values()))) if p else 0 for p in parts]
     per = max(max(lens), 1)
-    sharding = NamedSharding(mesh, P(axis))
-    cols = {}
+    host = {}
     for c, dt in template.items():
         stacked = np.zeros((S, per), dtype=dt)
         for s, p in enumerate(parts):
             if lens[s]:
                 stacked[s, : lens[s]] = p[c]
-        cols[c] = jax.device_put(stacked, sharding)
+        host[c] = stacked
     valid = np.zeros((S, per), dtype=bool)
     for s, n in enumerate(lens):
         valid[s, :n] = True
-    return ShardedTable(cols, jax.device_put(valid, sharding))
+    host[_VALID] = valid
+    cols = _put(host, NamedSharding(mesh, P(axis)), "exchange")
+    return ShardedTable(cols, cols.pop(_VALID))
 
 
 def _template(table: dict) -> dict[str, np.dtype]:
@@ -183,8 +196,9 @@ def _key_ranges(host_tables: list[dict], dev: ShardedTable | None,
             k = dev.cols[c]
             big = jnp.iinfo(k.dtype).max
             small = jnp.iinfo(k.dtype).min
-            los.append(int(jnp.min(jnp.where(dev.valid, k, big))))
-            his.append(int(jnp.max(jnp.where(dev.valid, k, small))))
+            with engine_span("sync", "key_range"):
+                los.append(int(jnp.min(jnp.where(dev.valid, k, big))))
+                his.append(int(jnp.max(jnp.where(dev.valid, k, small))))
         if not los:
             return None
         ranges[c] = (min(los), max(his))
@@ -236,7 +250,7 @@ def sharded_join(probe: ShardedTable, build: dict, on: Sequence[str],
     on = list(on)
     if how not in ("inner", "left"):
         return None
-    build = {k: np.asarray(v) for k, v in build.items()}
+    build = to_numpy(build, "join")
     if not (_int_keys(probe.cols, on) and _int_keys(build, on)):
         return None
     build_rows = len(next(iter(build.values()))) if build else 0
@@ -270,7 +284,10 @@ def _broadcast_hash_join(probe: ShardedTable, pcode: jax.Array, build: dict,
     # commit it to one device and pull every shard's probe through it)
     replicated = NamedSharding(probe.valid.sharding.mesh, P())
     order = np.argsort(bcode, kind="stable")
-    bsorted = jax.device_put(bcode[order].astype(np.int32), replicated)
+    payload = {k: v[order] for k, v in build.items() if k not in on}
+    payload[_CODES] = bcode[order].astype(np.int32)
+    payload = _put(payload, replicated, "broadcast")
+    bsorted = payload.pop(_CODES)
     B = int(bsorted.shape[0])
     idx = jnp.searchsorted(bsorted, pcode.astype(jnp.int32))
     idx_c = jnp.clip(idx, 0, B - 1)
@@ -287,8 +304,7 @@ def _broadcast_hash_join(probe: ShardedTable, pcode: jax.Array, build: dict,
         if k in on:
             continue
         name = k + suffixes[1] if k in overlap else k
-        col_sorted = jax.device_put(v[order], replicated)
-        taken = jnp.take(col_sorted, idx_c)
+        taken = jnp.take(payload[k], idx_c)
         if how == "left":
             if v.dtype.kind == "f":
                 taken = jnp.where(matched, taken, jnp.nan)

@@ -13,8 +13,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from .groupby import _factorize_multi
-from .table import Table, xp_of
-from ...obs.spans import traced_op
+from .table import Table, host_array, xp_of
+from ...obs.spans import engine_span, traced_op
 
 
 @traced_op("sort")
@@ -65,7 +65,7 @@ def apply_top_k(table: Table, by: Sequence[str], n: int,
     avoids per-call device dispatch, and device columns are only gathered
     at the final k-row index — with an O(rows) ``np.partition`` threshold
     pass for single numeric keys so only ~n candidate rows are argsorted."""
-    keys = [np.asarray(table[b]) for b in by]
+    keys = [host_array(table[b], "top_k") for b in by]
     sel = None
     if mode == "select":
         mask = None
@@ -103,7 +103,8 @@ def apply_drop_duplicates(table: Table, subset=None) -> Table:
     codes, _ = _factorize_multi(table, cols)
     xp = xp_of(table)
     if xp is jnp:
-        _, first_idx = jnp.unique(codes, return_index=True)
+        with engine_span("sync", "distinct"):    # the size of the result
+            _, first_idx = jnp.unique(codes, return_index=True)
         idx = jnp.sort(first_idx)
     else:
         _, first_idx = np.unique(codes, return_index=True)

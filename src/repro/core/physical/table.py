@@ -10,6 +10,10 @@ arrays plus a validity mask.  Physical operators dispatch on the array type
 Segment handoff payloads (``graph.Handoff``) are normalized here: host
 tables, scalars, or — for distributed→distributed chains — device-resident
 ``ShardedTable`` values that never round-trip through host memory.
+
+Every move of column data between numpy and the device goes through
+:func:`to_numpy`, :func:`to_jax` or :func:`host_array`, which put it under
+a ``transfer`` span named by its ``site`` and count its bytes.
 """
 from __future__ import annotations
 
@@ -17,6 +21,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from ...obs.spans import engine_span
 
 Table = dict
 
@@ -41,12 +47,40 @@ def table_nbytes(table: Table) -> int:
     return sum(int(v.nbytes) for v in table.values())
 
 
-def to_numpy(table: Table) -> Table:
-    return {k: np.asarray(v) for k, v in table.items()}
+def device_nbytes(arr) -> int:
+    """Bytes a host array takes on the device, where 8-byte numbers narrow
+    to 4 while x64 is off."""
+    return int(np.size(arr)) * jax.dtypes.canonicalize_dtype(
+        np.dtype(arr.dtype)).itemsize
 
 
-def to_jax(table: Table) -> Table:
-    return {k: jnp.asarray(v) for k, v in table.items()}
+def to_numpy(table: Table, site: str) -> Table:
+    """Host copy of a table; device columns move under a d2h transfer."""
+    moved = sum(int(v.nbytes) for v in table.values() if is_jax(v))
+    if not moved:
+        return {k: np.asarray(v) for k, v in table.items()}
+    with engine_span("transfer", site, dir="d2h", bytes=moved):
+        return {k: np.asarray(v) for k, v in table.items()}
+
+
+def to_jax(table: Table, site: str) -> Table:
+    """Device copy of a table; host columns move under an h2d transfer.
+    The span covers the host's part of the copy (staging and enqueue), not
+    the wait for it to land: the traced program is the untraced one."""
+    moved = sum(device_nbytes(v) for v in table.values() if not is_jax(v))
+    if not moved:
+        return {k: jnp.asarray(v) for k, v in table.items()}
+    with engine_span("transfer", site, dir="h2d", bytes=moved):
+        return {k: jnp.asarray(v) for k, v in table.items()}
+
+
+def host_array(arr, site: str) -> np.ndarray:
+    """``np.asarray`` of one value; a device array moves under a d2h
+    transfer."""
+    if not is_jax(arr):
+        return np.asarray(arr)
+    with engine_span("transfer", site, dir="d2h", bytes=int(arr.nbytes)):
+        return np.asarray(arr)
 
 
 def apply_concat(tables: list[Table]) -> Table:
@@ -74,9 +108,9 @@ def to_host_value(value):
     if isinstance(value, ShardedTable):
         return value.gather()
     if isinstance(value, dict):
-        return to_numpy(value)
+        return to_numpy(value, "handoff")
     if isinstance(value, (jax.Array, np.generic)):
-        arr = np.asarray(value)
+        arr = host_array(value, "handoff")
         return arr.item() if arr.ndim == 0 else arr
     return value
 
@@ -92,5 +126,5 @@ def handoff_value(node, device_arrays: bool = False):
     if isinstance(v, ShardedTable):
         v = v.gather()
     if isinstance(v, dict):
-        return to_jax(v) if device_arrays else v
+        return to_jax(v, "handoff") if device_arrays else v
     return v

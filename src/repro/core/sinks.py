@@ -61,6 +61,7 @@ def make_print(args: tuple, ctx: LaFPContext | None = None) -> G.SinkPrint:
 
 
 def render_sink(n: G.SinkPrint, data_vals: list[Any], ctx: LaFPContext):
+    from . import physical as X
     from .lazyframe import Result
     pieces = []
     for part in n.parts:
@@ -71,6 +72,8 @@ def render_sink(n: G.SinkPrint, data_vals: list[Any], ctx: LaFPContext):
             val = data_vals[v]
             if isinstance(val, dict):
                 val = Result(val)
+            elif X.is_jax(val):
+                val = X.host_array(val, "print")
             pieces.append(str(val))
     ctx.print_fn(" ".join(pieces) if len(pieces) > 1 else
                  (pieces[0] if pieces else ""))

@@ -27,7 +27,7 @@ from typing import Iterator
 import numpy as np
 
 from repro.core import graph as G
-from repro.obs.spans import io_span
+from repro.obs.spans import engine_span
 
 from .prefetch import prefetch_iter
 
@@ -71,8 +71,8 @@ def load_scan_partition(n: "G.Scan", pi: int, metrics=None, tracer=None
     numpy — same arrays and semantics the Filter operator would see, so
     pushdown on/off is bit-identical), and project to the output columns."""
     read_cols = pushdown_read_cols(n)
-    with io_span("load_partition", tracer=tracer, source=n.source.name,
-                 partition=pi) as sp:
+    with engine_span("io", "load_partition", tracer=tracer,
+                     source=n.source.name, partition=pi) as sp:
         part = n.source.load_partition(pi, read_cols)
         part = {k: np.asarray(v) for k, v in part.items()}
         nbytes = sum(int(a.nbytes) for a in part.values())

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import NOOP_SPAN, engine_span
 from . import ref
 from .filter_compact import filter_compact_columns as _compact_pallas
 from .groupby_sum import WORD_BITS
@@ -82,8 +83,12 @@ def groupby_sum_words(codes, values, num_groups: int,
 
 def words_to_int64(hi, lo) -> np.ndarray:
     """Host int64 totals of ``groupby_sum_words`` words (``lo`` may exceed
-    its 16 bits after words are added across shards)."""
-    return (np.asarray(hi).astype(np.int64) << WORD_BITS) + np.asarray(lo)
+    its 16 bits after words are added across shards): a sync, since the
+    host decides from them where the totals can live."""
+    on_device = isinstance(hi, jax.Array)
+    with (engine_span("sync", "int_sum") if on_device else NOOP_SPAN):
+        hi, lo = np.asarray(hi), np.asarray(lo)
+    return (hi.astype(np.int64) << WORD_BITS) + lo
 
 
 def filter_compact(values, mask, cfg: KernelConfig | None = None):

@@ -17,6 +17,9 @@ User surface (re-exported as ``repro.pandas.profile``):
     prof.to_chrome_trace()          # trace-event JSON; open in perfetto
     prof.save_chrome_trace("t.json")
 
+While the profile is attached, each span is also a ``repro:<name>``
+annotation in any ``jax.profiler`` trace taken meanwhile.
+
 Module map
 ----------
 ``spans``    Span / Tracer / no-op fast path / ``traced_op`` decorator
@@ -31,12 +34,12 @@ from .events import DEFAULT_TRACE_LIMIT, PlannerEvent, TraceLog
 from .export import to_chrome_trace, validate_chrome_trace, write_jsonl
 from .metrics import MetricsRegistry
 from .profile import Profile, profile
-from .spans import (NOOP_SPAN, Span, Tracer, metric_inc, op_span, traced_op,
-                    tracing_active)
+from .spans import (NOOP_SPAN, Span, Tracer, display_name, engine_span,
+                    metric_inc, traced_op, tracing_active)
 
 __all__ = [
-    "Span", "Tracer", "NOOP_SPAN", "tracing_active", "traced_op", "op_span",
-    "metric_inc", "MetricsRegistry", "TraceLog", "PlannerEvent",
+    "Span", "Tracer", "NOOP_SPAN", "tracing_active", "traced_op",
+    "engine_span", "display_name", "metric_inc", "MetricsRegistry", "TraceLog", "PlannerEvent",
     "DEFAULT_TRACE_LIMIT", "to_chrome_trace", "validate_chrome_trace",
     "write_jsonl", "Profile", "profile",
 ]

@@ -12,16 +12,9 @@ from __future__ import annotations
 import json
 from typing import Iterable
 
+from .spans import display_name
+
 _PHASES = {"X", "B", "E", "i", "I", "C", "M", "b", "e", "n", "s", "t", "f"}
-
-
-def _display_name(span) -> str:
-    op = span.attrs.get("op")
-    if span.name == "operator" and op:
-        return f"op:{op}"
-    if span.name == "segment":
-        return f"segment:{span.attrs.get('engine', '?')}"
-    return span.name
 
 
 def to_chrome_trace(spans: Iterable, counters: dict | None = None,
@@ -36,7 +29,7 @@ def to_chrome_trace(spans: Iterable, counters: dict | None = None,
     for s in spans:
         end = s.t1 if s.t1 is not None else s.t0
         events.append({
-            "name": _display_name(s),
+            "name": display_name(s),
             "cat": s.name,
             "ph": "X",
             "ts": (s.t0 - base) * 1e6,

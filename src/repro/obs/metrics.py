@@ -33,6 +33,15 @@ Counter glossary (what the built-in layers emit):
 ``io.pushdown_rows_in``/
 ``io.pushdown_rows_out``        rows entering / surviving pushed-down
                                 predicates at the scan layer
+``transfer.h2d_bytes``         column bytes copied from host numpy onto the
+                                device (device widths), one ``transfer``
+                                span each copy
+``transfer.d2h_bytes``         column bytes read back from the device into
+                                numpy, one ``transfer`` span each read
+``device.syncs``                blocking reads of a device value the host
+                                needs to go on (a compacted row count, the
+                                uniques of a factorization, int-sum
+                                totals), one ``sync`` span each
 ==============================  =============================================
 """
 from __future__ import annotations

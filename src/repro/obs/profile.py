@@ -19,7 +19,7 @@ DEFAULT_MAX_SPANS = 65_536
 _DETAIL_ATTRS = ("op", "engine", "force_reason", "segment", "rows_in",
                  "rows_out", "bytes_out", "bytes_moved", "peak_bytes",
                  "est_work", "segments", "device_resident", "status",
-                 "jit_seconds", "node_id", "payload")
+                 "jit_seconds", "node_id", "payload", "site", "dir", "bytes")
 
 
 class Profile:

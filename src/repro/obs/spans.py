@@ -18,6 +18,12 @@ tracing must cost nearly nothing on hot paths, so there are two gates:
 for segment/engine wall time, which feeds the planner's cost calibration
 (``StatsStore.record_runtime``) whether or not anyone is profiling — spans
 are the *single* timing instrumentation point.
+
+While a profile is attached, every span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro:<display name>``
+(:func:`display_name`), so a ``jax.profiler`` trace shows the engine's
+spans on the host threads beside the device's operations, on the same
+clock.  Without a profile no annotation is built.
 """
 from __future__ import annotations
 
@@ -33,6 +39,9 @@ _ids = itertools.count(1)
 _ACTIVE_TRACERS = 0
 _ACTIVE_LOCK = threading.Lock()
 
+# prefix of the profiler annotations that spans open while profiled
+ANNOTATION_PREFIX = "repro:"
+
 
 def tracing_active() -> bool:
     """True when any session in the process has an attached profile."""
@@ -44,7 +53,7 @@ class Span:
     it to the owning tracer's attached profiles."""
 
     __slots__ = ("id", "parent_id", "name", "t0", "t1", "attrs",
-                 "thread_id", "_tracer")
+                 "thread_id", "_tracer", "_annotation")
 
     def __init__(self, name: str, parent_id: int | None = None,
                  attrs: dict | None = None, tracer: "Tracer | None" = None):
@@ -54,6 +63,7 @@ class Span:
         self.attrs = dict(attrs) if attrs else {}
         self.thread_id = threading.get_ident()
         self._tracer = tracer
+        self._annotation = None
         self.t1: float | None = None
         self.t0 = time.perf_counter()
 
@@ -171,11 +181,21 @@ class Tracer:
         stack = self._stack()
         parent = stack[-1].id if stack else None
         sp = Span(name, parent_id=parent, attrs=attrs, tracer=self)
+        if self._profiles:
+            from jax.profiler import TraceAnnotation
+            sp._annotation = TraceAnnotation(
+                ANNOTATION_PREFIX + display_name(sp))
+            sp._annotation.__enter__()
         stack.append(sp)
         return sp
 
     def _finish(self, sp: Span) -> None:
         sp.t1 = time.perf_counter()
+        if sp._annotation is not None:
+            # each annotation is its own profiler activity, so spans that
+            # finish out of order still close theirs
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
         stack = self._stack()
         if stack and stack[-1] is sp:
             stack.pop()
@@ -214,29 +234,49 @@ def _current_tracer() -> Tracer | None:
     return getattr(get_context(), "tracer", None)
 
 
-def op_span(op: str, **attrs) -> Span | _NoopSpan:
-    """Operator span via the current session's tracer; no-op when the
-    process has no active profile (one int check) or this session's tracer
-    is disabled."""
-    if not _ACTIVE_TRACERS:
-        return NOOP_SPAN
-    tracer = _current_tracer()
-    if tracer is None or not tracer._profiles:
-        return NOOP_SPAN
-    return tracer.span("operator", op=op, **attrs)
+def engine_span(kind: str, op: str, tracer: Tracer | None = None,
+                **attrs) -> Span | _NoopSpan:
+    """A span of the engine's work: ``operator`` (a physical operator),
+    ``io`` (partition load, ingest), ``transfer`` (column
+    data between host and device: attrs ``dir`` = ``h2d``/``d2h`` and
+    ``bytes``) or ``sync`` (a blocking read of a device value the host
+    needs to go on).  ``op`` names the operator or IO step; for
+    ``transfer`` and ``sync`` it names the site and lands in ``site``.
 
-
-def io_span(op: str, tracer: Tracer | None = None, **attrs) -> Span | _NoopSpan:
-    """IO-layer span (partition load, prefetch, ingest) with the same
-    disabled-cost profile as :func:`op_span`.  Accepts an explicit tracer
-    for call sites off the session thread — the prefetch worker passes the
-    owning session's tracer, since the context lookup is thread-local."""
+    Transfers and syncs count on the session's metrics whether or not a
+    profile is attached (``transfer.h2d_bytes``/``transfer.d2h_bytes``,
+    ``device.syncs``); past that, the disabled cost is one module-global
+    int check.  An explicit ``tracer`` serves call sites off the session
+    thread: the prefetch worker passes the owning session's, since the
+    context lookup is thread-local."""
+    if kind == "transfer":
+        metric_inc(f"transfer.{attrs['dir']}_bytes", attrs["bytes"])
+    elif kind == "sync":
+        metric_inc("device.syncs")
     if not _ACTIVE_TRACERS:
         return NOOP_SPAN
     t = tracer if tracer is not None else _current_tracer()
     if t is None or not t._profiles:
         return NOOP_SPAN
-    return t.span("io", op=op, **attrs)
+    if kind in ("transfer", "sync"):
+        return t.span(kind, site=op, **attrs)
+    return t.span(kind, op=op, **attrs)
+
+
+def display_name(span) -> str:
+    """A span's name in exports and profiler annotations: ``op:<op>``,
+    ``io:<op>``, ``segment:<engine>``, ``transfer:<dir>``,
+    ``sync:<site>``, else its kind."""
+    a = span.attrs
+    if span.name in ("operator", "io") and a.get("op"):
+        return f"{'op' if span.name == 'operator' else 'io'}:{a['op']}"
+    if span.name == "segment":
+        return f"segment:{a.get('engine', '?')}"
+    if span.name == "transfer":
+        return f"transfer:{a.get('dir', '?')}"
+    if span.name == "sync":
+        return f"sync:{a.get('site', '?')}"
+    return span.name
 
 
 def metric_inc(name: str, n: int = 1) -> None:
@@ -256,7 +296,9 @@ def _rows_of(value) -> int | None:
     rows = getattr(value, "rows", None)
     if callable(rows) and hasattr(value, "valid"):    # ShardedTable
         try:
-            return int(value.rows())
+            # tracing's own read: not ``rows()``, whose sync span and
+            # ``device.syncs`` count the engine's reads only
+            return int(value.valid.sum())
         except Exception:  # noqa: BLE001 — metadata only, never fail the op
             return None
     return None
@@ -291,7 +333,7 @@ def traced_op(op: str):
         def wrapper(*args, **kwargs):
             if not _ACTIVE_TRACERS:
                 return fn(*args, **kwargs)
-            sp = op_span(op)
+            sp = engine_span("operator", op)
             if sp is NOOP_SPAN:
                 return fn(*args, **kwargs)
             with sp:

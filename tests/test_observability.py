@@ -2,6 +2,10 @@
 no-op fast path, counters, bounded trace logs, structured planner events,
 Chrome-trace/JSONL export, and the explain() span linkage."""
 import json
+import os
+import subprocess
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -293,3 +297,173 @@ def test_analyze_emits_span_when_profiled():
         spans = prof.find("analyze", mode="function")
         assert spans and "jit_seconds" in spans[0].attrs
         assert ctx.analysis.get("jit_seconds") is not None
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock, transfers and syncs.
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation built, and the thread it opened and closed on."""
+
+    def __init__(self):
+        self.built, self.open = [], set()
+        rec = self
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+                rec.built.append(self)
+
+            def __enter__(self):
+                self.entered = threading.get_ident()
+                rec.open.add(self)
+                return self
+
+            def __exit__(self, *exc):
+                self.exited = threading.get_ident()
+                rec.open.remove(self)
+
+        self.cls = Annotation
+
+    def names(self) -> set[str]:
+        return {a.name for a in self.built}
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec.cls)
+    return rec
+
+
+def test_spans_annotate_the_profiler_only_while_profiled(annotations):
+    ctx = get_context()
+    ctx.tracer.span("a").finish()
+    ctx.tracer.timed_span("segment", engine="eager").finish()
+    assert annotations.built == []
+    with profile():
+        with ctx.tracer.span("execute"):
+            assert annotations.open
+        ctx.tracer.timed_span("segment", engine="eager").finish()
+    assert annotations.names() == {"repro:execute", "repro:segment:eager"}
+    assert not annotations.open
+    ctx.tracer.timed_span("segment", engine="eager").finish()
+    assert len(annotations.built) == 2
+
+
+def test_out_of_order_finish_closes_every_annotation(annotations):
+    ctx = get_context()
+    with profile():
+        outer = ctx.tracer.span("outer")
+        inner = ctx.tracer.span("inner")
+        outer.finish()
+        assert len(annotations.open) == 1
+        inner.finish()
+    assert not annotations.open
+    assert ctx.tracer.current_span() is None
+
+
+def test_worker_thread_spans_annotate_on_their_own_thread(annotations):
+    from repro.obs import engine_span
+    ctx = get_context()
+    with profile():
+        worker = threading.Thread(target=lambda: engine_span(
+            "io", "load_partition", tracer=ctx.tracer).finish())
+        worker.start()
+        worker.join()
+    (a,) = annotations.built
+    assert a.name == "repro:io:load_partition"
+    assert a.entered == a.exited == worker.ident
+
+
+def _device_program():
+    """Eager, device-resident: a filter compacted on the device, an int
+    group-by sum, a host join and a TopK."""
+    df = pd.from_arrays({"k": np.arange(400) % 7, "v": np.arange(400),
+                         "x": np.arange(400.0)})
+    df = df[df["x"] > 10.0]
+    df["y"] = df["x"] * 2.0
+    df = df[df["y"] < 700.0]
+    df.groupby("k")["v"].sum().compute()
+    right = pd.from_arrays({"k": np.arange(7), "w": np.arange(7.0)})
+    df.merge(right, on="k").compute()
+    df.nlargest(3, "x").compute()
+
+
+def test_transfer_and_sync_spans_match_their_counters(annotations):
+    with pd.session(engine="eager", name="moves"):
+        with profile() as prof:
+            _device_program()
+    transfers = prof.find("transfer")
+    for direction in ("h2d", "d2h"):
+        moved = [s.attrs["bytes"] for s in transfers
+                 if s.attrs["dir"] == direction]
+        assert moved and sum(moved) > 0
+        assert sum(moved) == prof.counters[f"transfer.{direction}_bytes"]
+    syncs = prof.find("sync")
+    assert syncs and len(syncs) == prof.counters["device.syncs"]
+    sites = {s.attrs["site"] for s in transfers + syncs}
+    assert {"scan", "join", "top_k", "factorize"} <= sites
+    assert {"repro:execute", "repro:segment:eager", "repro:op:join",
+            "repro:io:load_partition", "repro:op:scan",
+            "repro:transfer:h2d", "repro:transfer:d2h",
+            "repro:sync:factorize"} <= annotations.names()
+    assert len(annotations.built) == len(prof.spans)
+
+
+def test_transfers_and_syncs_count_without_a_profile(annotations):
+    with pd.session(engine="eager", name="counted") as ctx:
+        before = ctx.metrics.snapshot()
+        _device_program()
+        counted = ctx.metrics.delta(before, ctx.metrics.snapshot())
+    assert counted["transfer.h2d_bytes"] > 0
+    assert counted["transfer.d2h_bytes"] > 0
+    assert counted["device.syncs"] > 0
+    assert annotations.built == []
+
+
+_SHARDED_SYNCS = """
+import sys
+import numpy as np
+sys.path.insert(0, "src")
+import jax
+import repro.pandas as pd
+from repro.obs import profile
+
+def program():
+    df = pd.from_arrays({"k": np.arange(4000) % 7, "v": np.arange(4000),
+                         "x": np.arange(4000.0)})
+    df = df[df["x"] > 10.0]
+    right = pd.from_arrays({"k": np.arange(7), "w": np.arange(7.0)})
+    df.merge(right, on="k").compute()
+    df.drop_duplicates(subset=("k",)).compute()
+    df.groupby("k")["v"].sum().compute()
+    len(df)
+
+counts = []
+for profiled in (False, True, False):
+    with pd.session(engine="distributed") as ctx:
+        before = ctx.metrics.snapshot()
+        if profiled:
+            with profile():
+                program()
+        else:
+            program()
+        counts.append(ctx.metrics.delta(
+            before, ctx.metrics.snapshot()).get("device.syncs", 0))
+print(jax.device_count(), *counts)
+"""
+
+
+def test_tracing_adds_no_syncs_to_a_sharded_program():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", _SHARDED_SYNCS], env=env,
+                         capture_output=True, text=True, check=True, cwd=".")
+    devices, plain, profiled, again = map(int, out.stdout.split()[-4:])
+    assert devices == 4
+    assert plain > 0
+    assert profiled == plain == again
