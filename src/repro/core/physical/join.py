@@ -1,24 +1,162 @@
-"""Host hash/sort join (build side = right).
+"""Equi-join (build side = right), on the device or on the host.
 
-Keys are factorized over the union of both sides so codes align; the probe
-side binary-searches the sorted build codes.  Pandas semantics: inner/left,
-probe-row order preserved, overlap columns suffixed, unmatched left-join
-float columns filled with NaN."""
+Pandas semantics either way: inner/left, probe-row order preserved,
+overlap columns suffixed, unmatched left-join float columns filled with
+NaN (other columns read build row 0).
+
+Device probe: where both sides are device tables joined on one integer
+key whose build values are unique (a foreign key → primary key join), the
+build keys are sorted on the device and every probe row binary-searches
+them; the right columns are gathered by the matched row, and no column
+leaves the device.
+
+Host join: everything else (numpy chunks, several keys, other key types,
+duplicate build keys).  Keys are factorized over the union of both sides
+so codes align; the probe side binary-searches the sorted build codes and
+many-to-many matches expand through a repeat index."""
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
-from .table import Table, to_jax, to_numpy, xp_of
-from ...obs.spans import traced_op
+from .table import Table, is_jax, table_rows, to_jax, to_numpy, xp_of
+from ...obs.spans import engine_span, metric_inc, op_attrs, traced_op
 
 
 @traced_op("join")
 def apply_join(left: Table, right: Table, on: Sequence[str], how="inner",
                suffixes=("_x", "_y")) -> Table:
+    out = (_device_join(left, right, on[0], how, suffixes)
+           if _device_probe_applies(left, right, on, how) else None)
+    path = "host" if out is None else "device"
+    metric_inc(f"join.{path}")
+    op_attrs("join", path=path)
+    if out is None:
+        out = _host_join(left, right, on, how, suffixes)
+    return out
+
+
+def _device_probe_applies(left: Table, right: Table, on: Sequence[str],
+                          how: str) -> bool:
+    """Device tables on one integer key whose two widths have a common
+    integer type the device holds (no 64-bit one while x64 is off)."""
+    if how not in ("inner", "left") or len(on) != 1:
+        return False
+    key = on[0]
+    if key not in left or key not in right or not all(
+            is_jax(v) for t in (left, right) for v in t.values()):
+        return False
+    common = jnp.promote_types(left[key].dtype, right[key].dtype)
+    return (common.kind in "iu"
+            and jax.dtypes.canonicalize_dtype(common) == common)
+
+
+# ints of each width narrower than a 32-bit word
+_NARROW_INT = {1: jnp.int8, 2: jnp.int16}
+
+
+def _words(v):
+    """A column as int32 words: (n, 1), or (n, 2) for 8-byte values."""
+    if v.dtype.kind == "b":
+        v = v.astype(jnp.int32)
+    elif v.dtype.itemsize < 4:
+        v = jax.lax.bitcast_convert_type(
+            v, _NARROW_INT[v.dtype.itemsize]).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type(v, jnp.int32).reshape(v.shape[0], -1)
+
+
+def _unwords(w, dtype):
+    """The column :func:`_words` made ``w`` from."""
+    if dtype.itemsize == 8:
+        return jax.lax.bitcast_convert_type(w, dtype)
+    w = w[:, 0]
+    if dtype.kind == "b":
+        return w != 0
+    if dtype.itemsize < 4:
+        w = w.astype(_NARROW_INT[dtype.itemsize])
+    return jax.lax.bitcast_convert_type(w, dtype)
+
+
+@functools.partial(jax.jit, static_argnames="how")
+def _probe(lkey, rkey, payload: dict, how: str):
+    """Look every probe key up in the sorted build keys.  Returns the
+    payload columns taken from the matched build row, the match mask, and
+    ``[build keys unique, matched rows]`` in one int32 array, for one
+    read.
+
+    The sorted keys and the payload are one table of int32 words, so a
+    probe row takes its key and its payload in one gather.  On one v5e, 25M
+    probe keys into 62K build keys with one payload column took 0.62 s this
+    way, against 0.93 s with a gather per column and 1.09 s with a gather
+    of the build row's index first; jnp.searchsorted's "sort" method took
+    1.09 s there against 3.56 s for "scan" and 3.55 s for "scan_unrolled"."""
+    common = jnp.promote_types(lkey.dtype, rkey.dtype)
+    lkey, rkey = lkey.astype(common), rkey.astype(common)
+    rows = rkey.shape[0]
+    if not rows:
+        match = jnp.zeros(lkey.shape, bool)
+        taken = {k: jnp.zeros(lkey.shape, v.dtype)
+                 for k, v in payload.items()}
+        unique = jnp.bool_(True)
+    else:
+        order = jnp.argsort(rkey)
+        bsorted = rkey[order]
+        unique = jnp.all(bsorted[1:] != bsorted[:-1])
+        cols = [_words(bsorted)] + [_words(v[order])
+                                    for v in payload.values()]
+        pos = jnp.minimum(jnp.searchsorted(bsorted, lkey, method="sort"),
+                          rows - 1)
+        picked = jnp.concatenate(cols, axis=1)[pos]
+        key, *vals = jnp.split(
+            picked, np.cumsum([c.shape[1] for c in cols])[:-1], axis=1)
+        match = _unwords(key, common) == lkey
+        taken = {k: _unwords(w, v.dtype)
+                 for (k, v), w in zip(payload.items(), vals)}
+    if how == "left":
+        # an unmatched row reads NaN, or where the column holds none build
+        # row 0 (zero without build rows), as the host join's does
+        taken = {k: jnp.where(
+            match, v, jnp.nan if jnp.issubdtype(v.dtype, jnp.floating)
+            else payload[k][0] if rows else jnp.zeros((), v.dtype))
+            for k, v in taken.items()}
+    stats = jnp.stack([unique.astype(jnp.int32),
+                       jnp.sum(match, dtype=jnp.int32)])
+    return taken, match, stats
+
+
+def _device_join(left: Table, right: Table, key: str, how: str,
+                 suffixes) -> Table | None:
+    """The device probe; ``None`` where the build keys repeat."""
+    payload = {k: v for k, v in right.items() if k != key}
+    taken, match, stats = _probe(left[key], right[key], payload, how=how)
+    with engine_span("sync", "join"):
+        unique, matched = (int(x) for x in np.asarray(stats))
+    if not unique:
+        return None
+    overlap = (set(left) & set(right)) - {key}
+    out = {key: left[key]}
+    for k, v in left.items():
+        if k != key:
+            out[k + suffixes[0] if k in overlap else k] = v
+    for k in payload:
+        out[k + suffixes[1] if k in overlap else k] = taken[k]
+    if how == "inner" and matched < table_rows(left):
+        # keep the matched rows in probe order, compacted as the fused
+        # rowwise chain compacts on the device
+        from ...kernels import ops as K
+        packed, _ = K.filter_compact_columns(
+            tuple(out.values()), match, K.KernelConfig(impl="xla"))
+        out = {c: v[:matched] for c, v in zip(out, packed)}
+    return out
+
+
+def _host_join(left: Table, right: Table, on: Sequence[str], how: str,
+               suffixes) -> Table:
     lj, rj = to_numpy(left, "join"), to_numpy(right, "join")
     was_jax = xp_of(left) is jnp
     lkeys, _ = _factorize_multi_np_pair(lj, rj, on)

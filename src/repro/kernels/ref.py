@@ -73,7 +73,7 @@ def filter_compact_ref(values: jnp.ndarray, mask: jnp.ndarray
     safe_idx = jnp.where(mask, idx, n)                    # masked rows → spill
     out = jnp.zeros((n + 1,), values.dtype).at[safe_idx].set(values)[:n]
     valid = jnp.arange(n) < count
-    return jnp.where(valid, out, 0), count
+    return jnp.where(valid, out, jnp.zeros_like(out)), count
 
 
 def zonemap_ref(values: jnp.ndarray, block: int

@@ -279,6 +279,17 @@ def display_name(span) -> str:
     return span.name
 
 
+def op_attrs(op: str, **attrs) -> None:
+    """Set attributes on this thread's open ``operator`` span ``op``: a
+    ``traced_op`` body saying how it ran.  Nothing while unprofiled."""
+    if not _ACTIVE_TRACERS:
+        return
+    t = _current_tracer()
+    sp = t.current_span() if t is not None and t._profiles else None
+    if sp is not None and sp.name == "operator" and sp.attrs.get("op") == op:
+        sp.set(**attrs)
+
+
 def metric_inc(name: str, n: int = 1) -> None:
     """Increment a counter on the current session's metrics registry."""
     from repro.core.context import get_context

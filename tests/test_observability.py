@@ -381,7 +381,7 @@ def test_worker_thread_spans_annotate_on_their_own_thread(annotations):
 
 def _device_program():
     """Eager, device-resident: a filter compacted on the device, an int
-    group-by sum, a host join and a TopK."""
+    group-by sum, a join (the device probe) and a TopK."""
     df = pd.from_arrays({"k": np.arange(400) % 7, "v": np.arange(400),
                          "x": np.arange(400.0)})
     df = df[df["x"] > 10.0]

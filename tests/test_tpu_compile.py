@@ -1,7 +1,8 @@
 """Compile the main path's kernels for a described (not attached) TPU v5e
 chip at the sizes the chip smoke check runs: 2e7-row group-by sums and
 compactions (the paper's 1.4 GB taxi set), zone maps of its 1.25M-row
-partitions, and the eager fused-chain body of the ``taxi_agg`` program.
+partitions, and the eager fused-chain body of the ``taxi_agg`` program;
+and the join's device probe at MovieLens 25M's ratings and movies.
 
 A compile here raises what the TPU's compiler would raise — misaligned
 blocks, unsupported Mosaic primitives, a program that does not fit HBM —
@@ -23,6 +24,7 @@ from repro.kernels.zonemap import zonemap
 ROWS = 20_000_000                 # taxi rows of the paper's 1.4 GB set
 PARTITION_ROWS = ROWS // 16       # its zone-map partitions
 HBM_BYTES = 16 * 10**9            # one v5e chip
+RATINGS, MOVIES = 25_000_095, 62_423   # MovieLens 25M
 
 
 @pytest.fixture(scope="module")
@@ -149,3 +151,16 @@ def test_taxi_agg_fused_chain_compiles(spec):
     compiled = body.lower(cols).compile()
     out_cols, mask = compiled.out_info
     assert out_cols["day"].shape == mask.shape == (ROWS,)
+
+
+def test_join_probe_compiles_for_ratings_movies(spec):
+    # ratings_join: every rating looks its movie up and takes its genres
+    # (about a minute: the 25M-key sort of jnp.searchsorted's method)
+    from repro.core.physical.join import _probe
+    c = _compile(lambda lkey, rkey, genres: _probe(
+        lkey, rkey, {"genres": genres}, how="inner"),
+        spec((RATINGS,), jnp.int32), spec((MOVIES,), jnp.int32),
+        spec((MOVIES,), jnp.int32))
+    taken, match, stats = c.out_info
+    assert taken["genres"].shape == match.shape == (RATINGS,)
+    assert stats.shape == (2,)
