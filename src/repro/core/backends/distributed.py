@@ -38,7 +38,7 @@ from .. import graph as G
 from .. import physical as X
 from ..context import LaFPContext
 from ..physical.sharded import ShardedTable
-from ...obs.spans import engine_span
+from ...obs.spans import engine_span, stage_traced
 from .eager import EagerBackend
 
 _DIST_OPS = ("scan", "filter", "project", "assign", "rename", "astype",
@@ -120,7 +120,8 @@ class DistributedBackend:
             child = self._eval(n.inputs[0], memo)
             if isinstance(child, ShardedTable):
                 try:
-                    return self._rowwise_sharded(n, child)
+                    with engine_span("operator", "sharded_rowwise"):
+                        return self._rowwise_sharded(n, child)
                 except Exception as e:  # noqa: BLE001 — e.g. host-numpy UDF
                     # exprs that cannot be jit-traced: gather and delegate
                     # like any other unsupported op — but never silently
@@ -139,7 +140,8 @@ class DistributedBackend:
             child = self._eval(n.inputs[0], memo)
             if isinstance(child, ShardedTable) and n.fn in ("sum", "mean",
                                                             "count", "min", "max"):
-                return self._reduce_sharded(n, child)
+                with engine_span("operator", "sharded_reduce"):
+                    return self._reduce_sharded(n, child)
             return self._fallback_node(n, [child])
         if isinstance(n, G.Length):
             child = self._eval(n.inputs[0], memo)
@@ -149,7 +151,8 @@ class DistributedBackend:
         if isinstance(n, G.GroupByAgg):
             child = self._eval(n.inputs[0], memo)
             if isinstance(child, ShardedTable):
-                dense = self._try_groupby_sharded(n, child)
+                with engine_span("operator", "sharded_groupby"):
+                    dense = self._try_groupby_sharded(n, child)
                 if dense is not None:
                     return dense
             return self._fallback_node(
@@ -232,6 +235,7 @@ class DistributedBackend:
 
             @partial(jax.jit)
             def upd(cols, valid):
+                stage_traced("filter")
                 mask = pred.evaluate(cols)
                 return valid & mask
 
@@ -244,6 +248,7 @@ class DistributedBackend:
 
             @partial(jax.jit)
             def mk(cols):
+                stage_traced("assign")
                 return expr.evaluate(cols)
 
             val = mk(t.cols)
@@ -286,6 +291,8 @@ class DistributedBackend:
 
         @partial(jax.jit)
         def run(col, valid):
+            stage_traced("reduce")
+
             def local(col, valid):
                 v = valid
                 if fn == "count":
@@ -345,6 +352,8 @@ class DistributedBackend:
 
         @partial(jax.jit, static_argnames=("gdom",))
         def run(karr, valid, vals, gdom):
+            stage_traced("groupby")
+
             def local(k, v, vals):
                 # sums and counts go through the kernel layer (the Pallas
                 # group-by on TPU): int sums as exact (hi, lo) words, and

@@ -325,66 +325,67 @@ def _shuffle_join(probe: ShardedTable, build: dict, bcode: np.ndarray,
     S = mesh.shape[axis]
     metric_inc("exchange.shuffles")
     metric_inc("exchange.shards", S)
-    parts, rowids, total = _host_shards(probe)
-    # exchange 1: co-locate by key code (shard-major iteration keeps rows in
-    # global order inside every destination bucket)
-    probe_buckets = [[] for _ in range(S)]
-    for part, rid in zip(parts, rowids):
-        if not len(rid):
-            continue
-        code = _host_code(part, on, spec)
-        dest = code % S
+    with engine_span("exchange", "join", shards=S):
+        parts, rowids, total = _host_shards(probe)
+        # exchange 1: co-locate by key code (shard-major iteration keeps
+        # rows in global order inside every destination bucket)
+        probe_buckets = [[] for _ in range(S)]
+        for part, rid in zip(parts, rowids):
+            if not len(rid):
+                continue
+            code = _host_code(part, on, spec)
+            dest = code % S
+            for s in range(S):
+                m = dest == s
+                if m.any():
+                    b = {k: v[m] for k, v in part.items()}
+                    b[_ROWID] = rid[m]
+                    probe_buckets[s].append(b)
+        build_buckets = []
+        bdest = bcode % S
         for s in range(S):
-            m = dest == s
-            if m.any():
-                b = {k: v[m] for k, v in part.items()}
-                b[_ROWID] = rid[m]
-                probe_buckets[s].append(b)
-    build_buckets = []
-    bdest = bcode % S
-    for s in range(S):
-        m = bdest == s
-        build_buckets.append({k: v[m] for k, v in build.items()})
-    # per-shard local join (the worker kernel)
-    joined: list[dict] = []
-    out_template: dict[str, np.dtype] | None = None
-    for s in range(S):
-        if probe_buckets[s]:
-            pb = {k: np.concatenate([b[k] for b in probe_buckets[s]])
-                  for k in probe_buckets[s][0]}
-        else:
-            pb = {k: np.asarray(v[:0]) for k, v in parts[0].items()}
-            pb[_ROWID] = np.zeros(0, np.int64)
-        j = apply_join(pb, build_buckets[s], on, how, suffixes)
-        joined.append(j)
-        if out_template is None:
-            out_template = _template(j)
-    # exchange 2: restore probe-row order — balanced row-id ranges per shard,
-    # then a local stable sort by row id (stability keeps the build-side
-    # match order the host kernel emitted)
-    out_buckets: list[list[dict]] = [[] for _ in range(S)]
-    for j in joined:
-        rid = j[_ROWID]
-        if not len(rid):
-            continue
-        dest = (rid * S) // max(total, 1)
+            m = bdest == s
+            build_buckets.append({k: v[m] for k, v in build.items()})
+        # per-shard local join (the worker kernel)
+        joined: list[dict] = []
+        out_template: dict[str, np.dtype] | None = None
         for s in range(S):
-            m = dest == s
-            if m.any():
-                out_buckets[s].append({k: v[m] for k, v in j.items()})
-    final_parts = []
-    for s in range(S):
-        if out_buckets[s]:
-            t = {k: np.concatenate([b[k] for b in out_buckets[s]])
-                 for k in out_buckets[s][0]}
-            order = np.argsort(t[_ROWID], kind="stable")
-            t = {k: v[order] for k, v in t.items()}
-        else:
-            t = {k: np.zeros(0, dt) for k, dt in out_template.items()}
-        t.pop(_ROWID, None)
-        final_parts.append(t)
-    template = {k: dt for k, dt in out_template.items() if k != _ROWID}
-    return _restack(final_parts, mesh, axis, template)
+            if probe_buckets[s]:
+                pb = {k: np.concatenate([b[k] for b in probe_buckets[s]])
+                      for k in probe_buckets[s][0]}
+            else:
+                pb = {k: np.asarray(v[:0]) for k, v in parts[0].items()}
+                pb[_ROWID] = np.zeros(0, np.int64)
+            j = apply_join(pb, build_buckets[s], on, how, suffixes)
+            joined.append(j)
+            if out_template is None:
+                out_template = _template(j)
+        # exchange 2: restore probe-row order — balanced row-id ranges per
+        # shard, then a local stable sort by row id (stability keeps the
+        # build-side match order the host kernel emitted)
+        out_buckets: list[list[dict]] = [[] for _ in range(S)]
+        for j in joined:
+            rid = j[_ROWID]
+            if not len(rid):
+                continue
+            dest = (rid * S) // max(total, 1)
+            for s in range(S):
+                m = dest == s
+                if m.any():
+                    out_buckets[s].append({k: v[m] for k, v in j.items()})
+        final_parts = []
+        for s in range(S):
+            if out_buckets[s]:
+                t = {k: np.concatenate([b[k] for b in out_buckets[s]])
+                     for k in out_buckets[s][0]}
+                order = np.argsort(t[_ROWID], kind="stable")
+                t = {k: v[order] for k, v in t.items()}
+            else:
+                t = {k: np.zeros(0, dt) for k, dt in out_template.items()}
+            t.pop(_ROWID, None)
+            final_parts.append(t)
+        template = {k: dt for k, dt in out_template.items() if k != _ROWID}
+        return _restack(final_parts, mesh, axis, template)
 
 
 # ---------------------------------------------------------------------------
@@ -401,48 +402,49 @@ def sharded_sort(t: ShardedTable, by: Sequence[str], ascending: bool,
     if any(b not in t.cols for b in by):
         return None
     S = mesh.shape[axis]
-    parts, _rowids, total = _host_shards(t)
-    template = _template(parts[0])
-    if total == 0:
-        return _restack([dict(p) for p in parts[:S]], mesh, axis, template)
-    # splitters from per-shard samples of the primary sort key
-    samples = []
-    for p in parts:
-        key = np.asarray(p[by[0]])
-        if key.size:
-            step = max(1, key.size // 64)
-            samples.append(np.sort(key)[::step])
-    merged = np.sort(np.concatenate(samples))
-    cut = [merged[(i * merged.size) // S] for i in range(1, S)]
-    splitters = np.asarray(cut, dtype=merged.dtype)
-    metric_inc("exchange.shuffles")
-    metric_inc("exchange.shards", S)
-    buckets: list[list[dict]] = [[] for _ in range(S)]
-    for p in parts:
-        key = np.asarray(p[by[0]])
-        if not key.size:
-            continue
-        dest = np.searchsorted(splitters, key, side="right")
+    with engine_span("exchange", "sort", shards=S):
+        parts, _rowids, total = _host_shards(t)
+        template = _template(parts[0])
+        if total == 0:
+            return _restack([dict(p) for p in parts[:S]], mesh, axis, template)
+        # splitters from per-shard samples of the primary sort key
+        samples = []
+        for p in parts:
+            key = np.asarray(p[by[0]])
+            if key.size:
+                step = max(1, key.size // 64)
+                samples.append(np.sort(key)[::step])
+        merged = np.sort(np.concatenate(samples))
+        cut = [merged[(i * merged.size) // S] for i in range(1, S)]
+        splitters = np.asarray(cut, dtype=merged.dtype)
+        metric_inc("exchange.shuffles")
+        metric_inc("exchange.shards", S)
+        buckets: list[list[dict]] = [[] for _ in range(S)]
+        for p in parts:
+            key = np.asarray(p[by[0]])
+            if not key.size:
+                continue
+            dest = np.searchsorted(splitters, key, side="right")
+            for s in range(S):
+                m = dest == s
+                if m.any():
+                    buckets[s].append({k: v[m] for k, v in p.items()})
+        sorted_parts = []
         for s in range(S):
-            m = dest == s
-            if m.any():
-                buckets[s].append({k: v[m] for k, v in p.items()})
-    sorted_parts = []
-    for s in range(S):
-        if buckets[s]:
-            merged_b = {k: np.concatenate([b[k] for b in buckets[s]])
-                        for k in buckets[s][0]}
-            keys = tuple(merged_b[b] for b in reversed(by))
-            idx = (np.lexsort(keys) if len(keys) > 1
-                   else np.argsort(keys[0], kind="stable"))
-            sorted_parts.append({k: v[idx] for k, v in merged_b.items()})
-        else:
-            sorted_parts.append({k: np.zeros(0, dt)
-                                 for k, dt in template.items()})
-    if not ascending:
-        sorted_parts = [{k: v[::-1] for k, v in p.items()}
-                        for p in reversed(sorted_parts)]
-    return _restack(sorted_parts, mesh, axis, template)
+            if buckets[s]:
+                merged_b = {k: np.concatenate([b[k] for b in buckets[s]])
+                            for k in buckets[s][0]}
+                keys = tuple(merged_b[b] for b in reversed(by))
+                idx = (np.lexsort(keys) if len(keys) > 1
+                       else np.argsort(keys[0], kind="stable"))
+                sorted_parts.append({k: v[idx] for k, v in merged_b.items()})
+            else:
+                sorted_parts.append({k: np.zeros(0, dt)
+                                     for k, dt in template.items()})
+        if not ascending:
+            sorted_parts = [{k: v[::-1] for k, v in p.items()}
+                            for p in reversed(sorted_parts)]
+        return _restack(sorted_parts, mesh, axis, template)
 
 
 # ---------------------------------------------------------------------------
@@ -459,58 +461,59 @@ def sharded_distinct(t: ShardedTable, subset, mesh, axis: str
     if not _int_keys(t.cols, cols):
         return None
     S = mesh.shape[axis]
-    parts, rowids, total = _host_shards(t)
-    template = _template(parts[0])
-    if total == 0:
-        return _restack([dict(p) for p in parts[:S]], mesh, axis, template)
-    ranges = _key_ranges(parts, None, cols)
-    if ranges is None:
-        return None
-    spec = _combined_radix(ranges, cols)
-    if spec is None:
-        return None
-    metric_inc("exchange.shuffles")
-    metric_inc("exchange.shards", S)
-    buckets: list[list[dict]] = [[] for _ in range(S)]
-    for part, rid in zip(parts, rowids):
-        if not len(rid):
-            continue
-        code = _host_code(part, cols, spec)
-        dest = code % S
+    with engine_span("exchange", "distinct", shards=S):
+        parts, rowids, total = _host_shards(t)
+        template = _template(parts[0])
+        if total == 0:
+            return _restack([dict(p) for p in parts[:S]], mesh, axis, template)
+        ranges = _key_ranges(parts, None, cols)
+        if ranges is None:
+            return None
+        spec = _combined_radix(ranges, cols)
+        if spec is None:
+            return None
+        metric_inc("exchange.shuffles")
+        metric_inc("exchange.shards", S)
+        buckets: list[list[dict]] = [[] for _ in range(S)]
+        for part, rid in zip(parts, rowids):
+            if not len(rid):
+                continue
+            code = _host_code(part, cols, spec)
+            dest = code % S
+            for s in range(S):
+                m = dest == s
+                if m.any():
+                    b = {k: v[m] for k, v in part.items()}
+                    b[_ROWID] = rid[m]
+                    buckets[s].append(b)
+        # local keep-first (bucket rows arrive in ascending row-id order)
+        kept: list[dict] = []
         for s in range(S):
-            m = dest == s
-            if m.any():
-                b = {k: v[m] for k, v in part.items()}
-                b[_ROWID] = rid[m]
-                buckets[s].append(b)
-    # local keep-first (bucket rows arrive in ascending row-id order)
-    kept: list[dict] = []
-    for s in range(S):
-        if buckets[s]:
-            merged = {k: np.concatenate([b[k] for b in buckets[s]])
-                      for k in buckets[s][0]}
-            kept.append(apply_drop_duplicates(merged, cols))
-        else:
-            kept.append(None)
-    # order-restoring exchange by kept row id
-    out_buckets: list[list[dict]] = [[] for _ in range(S)]
-    for k in kept:
-        if k is None or not len(k[_ROWID]):
-            continue
-        dest = (k[_ROWID] * S) // max(total, 1)
+            if buckets[s]:
+                merged = {k: np.concatenate([b[k] for b in buckets[s]])
+                          for k in buckets[s][0]}
+                kept.append(apply_drop_duplicates(merged, cols))
+            else:
+                kept.append(None)
+        # order-restoring exchange by kept row id
+        out_buckets: list[list[dict]] = [[] for _ in range(S)]
+        for k in kept:
+            if k is None or not len(k[_ROWID]):
+                continue
+            dest = (k[_ROWID] * S) // max(total, 1)
+            for s in range(S):
+                m = dest == s
+                if m.any():
+                    out_buckets[s].append({c: v[m] for c, v in k.items()})
+        final_parts = []
         for s in range(S):
-            m = dest == s
-            if m.any():
-                out_buckets[s].append({c: v[m] for c, v in k.items()})
-    final_parts = []
-    for s in range(S):
-        if out_buckets[s]:
-            merged = {k: np.concatenate([b[k] for b in out_buckets[s]])
-                      for k in out_buckets[s][0]}
-            order = np.argsort(merged[_ROWID], kind="stable")
-            merged = {k: v[order] for k, v in merged.items()}
-        else:
-            merged = {k: np.zeros(0, dt) for k, dt in template.items()}
-        merged.pop(_ROWID, None)
-        final_parts.append(merged)
-    return _restack(final_parts, mesh, axis, template)
+            if out_buckets[s]:
+                merged = {k: np.concatenate([b[k] for b in out_buckets[s]])
+                          for k in out_buckets[s][0]}
+                order = np.argsort(merged[_ROWID], kind="stable")
+                merged = {k: v[order] for k, v in merged.items()}
+            else:
+                merged = {k: np.zeros(0, dt) for k, dt in template.items()}
+            merged.pop(_ROWID, None)
+            final_parts.append(merged)
+        return _restack(final_parts, mesh, axis, template)

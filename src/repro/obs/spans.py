@@ -237,7 +237,9 @@ def _current_tracer() -> Tracer | None:
 def engine_span(kind: str, op: str, tracer: Tracer | None = None,
                 **attrs) -> Span | _NoopSpan:
     """A span of the engine's work: ``operator`` (a physical operator),
-    ``io`` (partition load, ingest), ``transfer`` (column
+    ``io`` (partition load, ingest), ``exchange`` (one host shuffle round
+    of a sharded table: the pull of its shards, the bucketing, the push
+    back; ``op`` names the operator that shuffles), ``transfer`` (column
     data between host and device: attrs ``dir`` = ``h2d``/``d2h`` and
     ``bytes``) or ``sync`` (a blocking read of a device value the host
     needs to go on).  ``op`` names the operator or IO step; for
@@ -265,11 +267,11 @@ def engine_span(kind: str, op: str, tracer: Tracer | None = None,
 
 def display_name(span) -> str:
     """A span's name in exports and profiler annotations: ``op:<op>``,
-    ``io:<op>``, ``segment:<engine>``, ``transfer:<dir>``,
-    ``sync:<site>``, else its kind."""
+    ``io:<op>``, ``exchange:<op>``, ``trace:<op>``, ``segment:<engine>``,
+    ``transfer:<dir>``, ``sync:<site>``, else its kind."""
     a = span.attrs
-    if span.name in ("operator", "io") and a.get("op"):
-        return f"{'op' if span.name == 'operator' else 'io'}:{a['op']}"
+    if span.name in ("operator", "io", "exchange", "trace") and a.get("op"):
+        return f"{'op' if span.name == 'operator' else span.name}:{a['op']}"
     if span.name == "segment":
         return f"segment:{a.get('engine', '?')}"
     if span.name == "transfer":
@@ -277,6 +279,18 @@ def display_name(span) -> str:
     if span.name == "sync":
         return f"sync:{a.get('site', '?')}"
     return span.name
+
+
+def stage_traced(op: str) -> None:
+    """An instant ``trace`` event naming stage ``op`` (``filter``,
+    ``assign``, ``reduce``, ``groupby``): called from inside a jitted
+    body, it fires each time JAX traces that body, never on a call the
+    jit cache serves.  Nothing while unprofiled."""
+    if not _ACTIVE_TRACERS:
+        return
+    t = _current_tracer()
+    if t is not None:
+        t.event("trace", op=op)
 
 
 def op_attrs(op: str, **attrs) -> None:

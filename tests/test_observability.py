@@ -467,3 +467,39 @@ def test_tracing_adds_no_syncs_to_a_sharded_program():
     assert devices == 4
     assert plain > 0
     assert profiled == plain == again
+
+
+def _sharded_program():
+    """Distributed: an assign and a filter on it (rowwise stages), a
+    group-by, a reduction and a distinct (the host exchange)."""
+    df = pd.from_arrays({"k": np.arange(400) % 7, "v": np.arange(400),
+                         "x": np.arange(400.0)})
+    df["y"] = df["x"] * 2.0
+    df = df[df["y"] < 700.0]
+    df.groupby("k")["y"].sum().compute()
+    df["y"].max().compute()
+    df.drop_duplicates(subset=("k",)).compute()
+
+
+def test_sharded_stages_and_exchanges_span_only_while_profiled(annotations):
+    import jax
+    with pd.session(engine="distributed"):
+        _sharded_program()
+    assert annotations.built == []
+    with pd.session(engine="distributed"):
+        with profile() as prof:
+            _sharded_program()
+    ops = {s.attrs["op"] for s in prof.find("operator")}
+    assert {"sharded_rowwise", "sharded_groupby", "sharded_reduce"} <= ops
+    # one event per trace of a stage's jitted body, at the stage's name
+    traced = {s.attrs["op"] for s in prof.find("trace")}
+    assert traced == {"assign", "filter", "groupby", "reduce"}
+    (exchange,) = prof.find("exchange")
+    assert exchange.attrs == {"op": "distinct",
+                              "shards": jax.device_count()}
+    moves = [s for s in prof.find("transfer") if s.parent_id == exchange.id]
+    assert {(s.attrs["site"], s.attrs["dir"]) for s in moves} == {
+        ("exchange", "d2h"), ("exchange", "h2d")}
+    assert {"repro:op:sharded_rowwise", "repro:op:sharded_groupby",
+            "repro:exchange:distinct", "repro:trace:filter",
+            "repro:trace:groupby"} <= annotations.names()
