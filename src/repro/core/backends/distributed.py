@@ -6,10 +6,11 @@ row count and stacked to ``(n_shards, rows)`` with a validity mask
 (``physical.ShardedTable``).  Row-wise ops and mask updates run inside a
 single jit+shard_map program per pipeline stage; reductions and group-bys
 compute shard-local partials and combine with ``jax.lax.psum`` over the data
-axis.  Group-by keys must be dictionary-coded / small-domain ints (the
-metadata store guarantees this for category columns), giving a dense
-``segment_sum`` of size G per shard — the same dense code domain the group-by
-kernel uses on TPU.
+axis.  Group-by keys must be non-negative ints whose domain ``G = max + 1``
+fits ``max(2^16, rows per shard)`` (dictionary codes, ids), giving a dense
+``segment_sum`` of size G per shard — the group-by kernel's dense code
+domain on TPU, XLA's segment sum past its VMEM budget; other keys gather to
+the host.
 
 Join, sort, and distinct are *native* (``physical.sharded``): broadcast-hash
 join for small unique-key build sides (device-resident, shape-preserving),
@@ -38,7 +39,7 @@ from .. import graph as G
 from .. import physical as X
 from ..context import LaFPContext
 from ..physical.sharded import ShardedTable
-from ...obs.spans import engine_span, stage_traced
+from ...obs.spans import engine_span, metric_inc, op_attrs, stage_traced
 from .eager import EagerBackend
 
 _DIST_OPS = ("scan", "filter", "project", "assign", "rename", "astype",
@@ -127,7 +128,6 @@ class DistributedBackend:
                     # like any other unsupported op — but never silently
                     # (a genuine native-kernel bug must stay visible)
                     from ...obs.events import PlannerEvent
-                    from ...obs.spans import metric_inc
                     self._ctx.planner_trace.append(PlannerEvent(
                         f"distributed: {n.op}#{n.id} native path failed, "
                         f"falling back ({type(e).__name__}: {e})",
@@ -153,6 +153,9 @@ class DistributedBackend:
             if isinstance(child, ShardedTable):
                 with engine_span("operator", "sharded_groupby"):
                     dense = self._try_groupby_sharded(n, child)
+                    path = "host" if dense is None else "dense"
+                    metric_inc(f"groupby.sharded_{path}")
+                    op_attrs("sharded_groupby", path=path)
                 if dense is not None:
                     return dense
             return self._fallback_node(
@@ -331,17 +334,21 @@ class DistributedBackend:
         return out
 
     def _try_groupby_sharded(self, n: G.GroupByAgg, t: ShardedTable):
-        """Dense group-by when the key domain is small & known (dict codes)."""
+        """Dense group-by when the int key is non-negative and its domain
+        fits a dense accumulator no larger than ``max(2^16, shard rows)``,
+        so per-shard partials stay O(shard); None sends it to the host."""
         if len(n.keys) != 1:
             return None
         key = n.keys[0]
         karr = t.cols.get(key)
         if karr is None or karr.dtype.kind not in "iu":
             return None
+        k = jnp.where(t.valid, karr, 0)
         with engine_span("sync", "group_domain"):
-            kmax = int(jnp.max(jnp.where(t.valid, karr, 0)))
+            kmin, kmax = np.asarray(
+                jnp.stack([jnp.min(k), jnp.max(k)])).tolist()
         G_dom = kmax + 1
-        if G_dom > 1 << 16:
+        if kmin < 0 or G_dom > max(1 << 16, t.valid.shape[1]):
             return None
         mesh, axis = self.mesh, self.axis
         fns = {out: fn for out, (_c, fn) in n.aggs.items()}

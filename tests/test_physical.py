@@ -238,6 +238,71 @@ def test_int_group_sums_past_int32_are_exact(rng, engine):
                                want / np.bincount(arrays["k"]), rtol=1e-4)  # f32 sums
 
 
+def _group_keys(rng, shards, case):
+    """Int keys for one case of the sharded group-by's domain gate."""
+    if case == "ids_past_2_16_within_shard":
+        k = rng.integers(0, 70_000, 80_000 * shards)
+        k[-1] = 69_999                       # domain 70,000 > 2^16
+        return k
+    k = rng.integers(0, 40, 3000)
+    if case == "negative_key":
+        k[::7] = -3
+    elif case == "domain_past_shard":
+        k[-1] = 1 << 17                      # > max(2^16, 3000 rows)
+    elif case == "domain_2_16":
+        k[-1] = (1 << 16) - 1
+    return k
+
+
+@pytest.mark.parametrize("case,path", [
+    ("ids_past_2_16_within_shard", "dense"),
+    ("negative_key", "host"),
+    ("domain_past_shard", "host"),
+    ("dict_codes", "dense"),
+    ("domain_2_16", "dense"),
+])
+def test_sharded_groupby_domain_gate(rng, case, path):
+    """The distributed group-by stays on the devices when its int key is
+    non-negative and its domain fits ``max(2^16, rows per shard)``, and
+    gathers to the host otherwise; either way it agrees with pandas."""
+    import pandas
+    from repro.obs import profile
+    backend = DistributedBackend()
+    k = _group_keys(rng, backend._n_shards(), case).astype(np.int64)
+    n = len(k)
+    k[:8] = k[0]
+    arrays = {"k": k,
+              "cents": rng.integers(0, 1000, n).astype(np.int64),
+              "f": rng.integers(0, 400, n) / 2}
+    arrays["cents"][:8] = 1 << 30            # group k[0] sums past 2^31
+    node = G.GroupByAgg(
+        G.Scan(core.InMemorySource(arrays, 1 << 14)), ["k"],
+        {"total": ("cents", "sum"), "avg": ("f", "mean"), "n": ("f", "count"),
+         "lo": ("cents", "min"), "hi": ("f", "max")})
+    with profile() as prof:
+        res = backend.execute([node], get_context())[node.id]
+    (op,) = [s for s in prof.find("operator")
+             if s.attrs.get("op") == "sharded_groupby"]
+    assert op.attrs["path"] == path
+    assert prof.counters[f"groupby.sharded_{path}"] == 1
+    other = "host" if path == "dense" else "dense"
+    assert f"groupby.sharded_{other}" not in prof.counters
+    gathers = [s for s in prof.find("transfer")
+               if s.attrs.get("site") == "gather"]
+    assert bool(gathers) == (path == "host")
+    want = pandas.DataFrame(arrays).groupby("k").agg(
+        total=("cents", "sum"), avg=("f", "mean"), n=("f", "count"),
+        lo=("cents", "min"), hi=("f", "max"))
+    assert want["total"].max() > 1 << 31
+    order = np.argsort(np.asarray(res["k"]))
+    np.testing.assert_array_equal(np.asarray(res["k"])[order], want.index)
+    for c in ("total", "n", "lo", "hi"):
+        np.testing.assert_array_equal(
+            np.asarray(res[c])[order], want[c].to_numpy(), err_msg=c)
+    np.testing.assert_allclose(np.asarray(res["avg"], np.float64)[order],
+                               want["avg"].to_numpy(), rtol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # Device-resident handoff: distributed→distributed chain, no host gather
 
